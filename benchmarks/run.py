@@ -38,6 +38,9 @@ def main() -> None:
                     help="where to write the throughput trajectory JSON")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import accuracy, anomaly, flow_scalability, fusion_ablation, resources, throughput
 
     benches = {

@@ -21,7 +21,7 @@ paper's compiler enforces on the P4 target before deployment:
   build-time version of the kernel docstring's working-set math.
 * **PGA104** — tile alignment: ladder buckets that silently dispatch hidden
   pad rows (the kernel pads the batch up to its tile multiple, uncounted by
-  ``pad_waste``), and mxu-strategy LUT widths missing 128-lane alignment.
+  ``pad_waste``), and single-bank mxu LUT tiles missing 128-lane alignment.
 * **PGA105** — fusion-rejection explanations: why each adjacent chained
   bank pair is NOT inside one :class:`FusedBankStack` (v/C mismatch,
   chaining break, ``nmax_cap`` split, ``fuse=False``, or a family builder
@@ -264,22 +264,21 @@ def _single_vmem_bytes(bank) -> int:
 
 
 def _stack_vmem_bytes(stack, max_bucket: int) -> int:
-    """Worst-case VMEM working set of one stacked pallas_call: the batch
-    tile's x + repartition buffer, plus EVERY per-layer operand riding
-    whole (that is the point of the fusion — the activation never leaves
-    VMEM)."""
+    """Worst-case VMEM working set of one stacked pallas_call (kernel.py
+    module docstring): the batch tile's ``[W, Tt]`` input/output blocks,
+    EVERY per-layer operand riding whole (that is the point of the fusion —
+    the activation never leaves VMEM), and the ``[K·C, Tt]`` descent
+    intermediates of one layer."""
     ll = len(stack.banks)
-    kmax = max(stack.ks)
-    c = stack.banks[0].layer.num_centroids
-    i = c - 1
-    nmax = int(stack.lut.shape[-1])
-    v = stack.v
+    kc = max(stack.ks) * stack.banks[0].layer.num_centroids
+    w = stack.width
     bt = min(stack.block_t, max(max_bucket, 1))
-    floats = (bt * stack.ks[0] * v + bt * kmax * v          # x + repartition
-              + ll * kmax * i * (v + 1)                     # feat_oh + thr
-              + ll * kmax * c * nmax                        # f32 LUT stack
-              + ll * nmax + bt * nmax + bt * stack.n_out)   # bias + y + out
-    return 4 * floats + ll * kmax * c * nmax                # + int8 stack
+    floats = (2 * w * bt                                  # x^T + out^T tiles
+              + ll * kc * (w + 1)                         # select + thr
+              + ll * w * kc                               # f32 LUT stack
+              + ll * kc + ll * w                          # scales + bias
+              + 4 * kc * bt)                              # vals/bits/one-hots
+    return 4 * floats + ll * w * kc                       # + int8 stack
 
 
 def _iter_steps(plan):
@@ -301,7 +300,7 @@ def _check_vmem(plan, cfg: AuditConfig):
         if hasattr(step, "ks"):                      # FusedBankStack
             need = _stack_vmem_bytes(step, max_bucket)
             shape = (f"L={len(step.banks)}, Kmax={max(step.ks)}, "
-                     f"Nmax={int(step.lut.shape[-1])}")
+                     f"W={step.width}, bt={step.block_t}")
         else:
             need = _single_vmem_bytes(step)
             shape = (f"bt={step.block_t}, bk={step.block_k}, "
@@ -343,19 +342,18 @@ def _check_alignment(plan, cfg: AuditConfig):
                     f"tile {bt} ({site}): the kernel path silently pads "
                     f"{hidden} extra rows per call, uncounted by pad_waste",
                     {"bucket": bucket, "tile": bt, "hidden_rows": hidden})
-    # MXU lane alignment: the mxu strategy's matmul wants the LUT column
-    # tile 128-lane aligned; misalignment wastes systolic-array lanes.
+    # MXU lane alignment: the single-bank mxu matmul wants the LUT column
+    # tile 128-lane aligned; misalignment wastes systolic-array lanes. (A
+    # fused stack puts the batch tile on lanes, which the ladder aligns.)
     for site, step in _iter_steps(plan):
-        if step.strategy != "mxu":
+        if step.strategy != "mxu" or hasattr(step, "ks"):
             continue
-        width = int(step.lut.shape[-1]) if hasattr(step, "ks") else step.block_n
-        what = "Nmax" if hasattr(step, "ks") else "block_n"
-        if width % R.MXU_LANES:
+        if step.block_n % R.MXU_LANES:
             yield AuditFinding(
                 "PGA104", "warning", site,
-                f"mxu strategy with {what}={width} not {R.MXU_LANES}-lane "
-                "aligned — MXU tiles run partially empty",
-                {"width": width, "lanes": R.MXU_LANES})
+                f"mxu strategy with block_n={step.block_n} not "
+                f"{R.MXU_LANES}-lane aligned — MXU tiles run partially empty",
+                {"width": step.block_n, "lanes": R.MXU_LANES})
 
 
 def _unfused_reasons(a, b) -> list[str]:

@@ -15,10 +15,10 @@ module does the same for the TPU realization:
     Fusion: a maximal run of shape-compatible consecutive banks (same group
     width and centroid count, each bank's output feeding the next's input)
     compiles into ONE stacked Pallas kernel invocation
-    (``fuzzy_lut_stack_pallas`` / ``..._q8``) — operands stacked to
-    ``[L, Kmax, C, Nmax]`` at plan build, the inter-bank re-partition +
-    bias (+ q8 dequant) folded into the kernel loop so activations never
-    leave VMEM between banks. Incompatible runs, the ``gather``/``onehot``
+    (``fuzzy_lut_stack_pallas`` / ``..._q8``) — operands stacked into the
+    kernel's transposed ``[L, W, Kmax·C]`` layout at plan build, the
+    inter-bank re-partition + bias (+ q8 dequant) folded into the kernel
+    loop so activations never leave VMEM between banks. Incompatible runs, the ``gather``/``onehot``
     backends, and the RNN/CNN structural steps fall back to the per-bank
     path; ``fuse=False`` on :func:`build_plan` disables grouping entirely
     (the fusion config participates in plan_for's memo key).
@@ -56,19 +56,19 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.analysis.sanitizer import make_lock
 
 from repro.core.amm import PegasusLinear, apply_gather, apply_onehot
 from repro.core.fuzzy_tree import hard_index
 from repro.kernels.fuzzy_lut.kernel import (
-    STACK_BLOCK_T,
     default_interpret,
     fuzzy_lut_pallas,
     fuzzy_lut_stack_pallas,
     resolve_strategy,
+    stack_block_t,
+    stack_layout,
 )
 from repro.kernels.fuzzy_lut.ops import prepare_feat_onehot, quantized_lut_cached
 from repro.kernels.fuzzy_lut.quantized import (
@@ -92,9 +92,9 @@ __all__ = [
     "resolve_devices",
 ]
 
-# Per-group cap on a fused stack's padded output width: the stacked operands
-# are [L, Kmax, C, Nmax], so one wide bank joining a narrow run multiplies
-# EVERY member's LUT rows (and the kernel's VMEM working set) by Nmax/N.
+# Per-group cap on a fused stack's padded output width: the stacked LUTs
+# are [L, W, Kmax·C] with W ≥ Nmax, so one wide bank joining a narrow run
+# multiplies EVERY member's LUT (and the kernel's VMEM working set) by Nmax/N.
 # Groups split rather than pad past this; equal-width wide banks may still
 # fuse above it because they add no padding (see fuse_banks). 2048 clears
 # every paper-scale head (N ≤ a few hundred) while bounding worst-case
@@ -371,7 +371,6 @@ class FusedBankStack:
         self.depth = banks[0].depth
         self.ks = tuple(l.num_groups for l in layers)
         self.n_out = layers[-1].out_features
-        self.block_t = STACK_BLOCK_T
         self.interpret = banks[0].interpret
         self.strategy = banks[0].strategy
 
@@ -397,27 +396,30 @@ class FusedBankStack:
             scales = scales.at[l, :k].set(bank.scales[:k])
             if bank.layer.bias is not None:
                 bias = bias.at[l, :n].set(bank.layer.bias)
-        self.feat_oh, self.thr = feat_oh, thr
-        self.lut, self.lut_q8 = lut, lut_q8
-        self.scales, self.bias = scales, bias
+        # the kernel's transposed layout, built once here (stack_layout)
+        self.sel, self.thr, self.lut, self.bias, self.scales = stack_layout(
+            feat_oh, thr, lut, bias, scales)
+        self.lut_q8 = stack_layout(feat_oh, thr, lut_q8, bias)[2]
+        self.width = int(self.lut.shape[1])      # padded activation rows W
+        self.block_t = stack_block_t(kmax * c)
         STATS.layout_builds += 1
 
     # -- pytree protocol ----------------------------------------------------
 
     def tree_flatten(self):
-        children = (tuple(self.banks), self.feat_oh, self.thr, self.lut,
+        children = (tuple(self.banks), self.sel, self.thr, self.lut,
                     self.lut_q8, self.scales, self.bias)
-        aux = (self.ks, self.v, self.depth, self.n_out, self.block_t,
-               self.interpret, self.strategy)
+        aux = (self.ks, self.v, self.depth, self.n_out, self.width,
+               self.block_t, self.interpret, self.strategy)
         return children, aux
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         obj = object.__new__(cls)
-        (banks, obj.feat_oh, obj.thr, obj.lut,
+        (banks, obj.sel, obj.thr, obj.lut,
          obj.lut_q8, obj.scales, obj.bias) = children
         obj.banks = list(banks)
-        (obj.ks, obj.v, obj.depth, obj.n_out, obj.block_t,
+        (obj.ks, obj.v, obj.depth, obj.n_out, obj.width, obj.block_t,
          obj.interpret, obj.strategy) = aux
         return obj
 
@@ -433,23 +435,20 @@ class FusedBankStack:
         if backend not in ("kernel", "kernel_q8"):
             return self._per_bank(x, backend)
         lead = x.shape[:-1]
-        xg = x.reshape(-1, self.ks[0], self.v).astype(jnp.float32)
-        t = xg.shape[0]
+        xf = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
+        t = xf.shape[0]
         bt = min(self.block_t, t)
-        xg = _pad_to(xg, 0, bt)
+        xf = _pad_to(xf, 0, bt)
+        kw = dict(depth=self.depth, ks=self.ks, n_out=self.n_out, block_t=bt,
+                  interpret=self.interpret, strategy=self.strategy)
         try:
             if backend == "kernel":
                 y = fuzzy_lut_stack_pallas(
-                    xg, self.feat_oh, self.thr, self.lut, self.bias,
-                    depth=self.depth, ks=self.ks, n_out=self.n_out,
-                    block_t=bt, interpret=self.interpret,
-                    strategy=self.strategy)
+                    xf, self.sel, self.thr, self.lut, self.bias, **kw)
             else:
                 y = fuzzy_lut_stack_q8_pallas(
-                    xg, self.feat_oh, self.thr, self.lut_q8, self.scales,
-                    self.bias, depth=self.depth, ks=self.ks,
-                    n_out=self.n_out, block_t=bt, interpret=self.interpret,
-                    strategy=self.strategy)
+                    xf, self.sel, self.thr, self.lut_q8, self.scales,
+                    self.bias, **kw)
         except ValueError:
             # mis-padded operand stack (e.g. hand-built): the kernel refuses
             # loudly and the per-bank chain serves the call (and does its own
@@ -492,7 +491,7 @@ def fuse_banks(banks: Sequence[CompiledBank], *,
 
     ``nmax_cap`` bounds each group's padded output width (``None`` = no
     cap): a run splits rather than letting one wide bank balloon a narrow
-    stack's ``[L, Kmax, C, Nmax]`` VMEM footprint — the wide bank starts
+    stack's ``[L, W, Kmax·C]`` VMEM footprint — the wide bank starts
     its own run (and may still fuse with equally-wide neighbors, which add
     no padding).
 
@@ -630,6 +629,12 @@ class ExecutionPlan:
         self.family = family
         self.buckets = tuple(sorted(bucket_sizes)) if bucket_sizes else DEFAULT_BUCKETS
         self.devices = resolve_devices(devices)
+        # PLACED mode: per-device replicas of the bank state, built lazily
+        # on first use (cross-device copies of KiB-scale LUT tables)
+        self._replicas: dict = {}                # guarded-by: _replica_lock
+        self._replica_lock = make_lock("plan._replica_lock")
+        # what an unplaced call reads (immutable after construction)
+        self._call_state = state
         mesh = None
         if self.devices is not None and len(self.devices) > 1:
             bad = [b for b in self.buckets if b % len(self.devices)]
@@ -640,11 +645,13 @@ class ExecutionPlan:
                     "split evenly across the batch axis (pass bucket_sizes "
                     "that the device count divides)")
             mesh = Mesh(np.asarray(self.devices), ("batch",))
+            # bank state replicated on the mesh once, here: left on the
+            # default device it would be re-broadcast from device 0 per call
+            self._call_state = jax.device_put(
+                state, NamedSharding(mesh, PartitionSpec()))
         self._mesh = mesh
-        # PLACED mode: per-device replicas of the bank state, built lazily
-        # on first use (cross-device copies of KiB-scale LUT tables)
-        self._replicas: dict = {}                # guarded-by: _replica_lock
-        self._replica_lock = make_lock("plan._replica_lock")
+        self._batch_sharding = (None if mesh is None else
+                                NamedSharding(mesh, PartitionSpec("batch")))
         # compile-cache instrumentation (per plan; STATS mirrors globally).
         # The counters live in a detached holder: _pure must not close over
         # `self`, or plan ↔ jit-closure would form a reference cycle and an
@@ -683,13 +690,13 @@ class ExecutionPlan:
                 return run(state, inputs)
             # SHARDED mode: batch axis split across the mesh, bank state
             # replicated (P() prefix-spec broadcasts over the whole state
-            # pytree). Rows never interact, so no collectives — check_rep
+            # pytree). Rows never interact, so no collectives — check_vma
             # is off because the Pallas calls carry no replication rules.
-            return shard_map(
+            return jax.shard_map(
                 run, mesh=mesh,
                 in_specs=(PartitionSpec(), PartitionSpec("batch")),
                 out_specs=PartitionSpec("batch"),
-                check_rep=False)(state, inputs)
+                check_vma=False)(state, inputs)
 
         # inputs (arg 1) are DONATED: the bucket ladder hands the jitted
         # forward a padded buffer the plan itself owns, so XLA may reuse its
@@ -730,23 +737,35 @@ class ExecutionPlan:
                 lambda bank, x: bank.apply(x, be), self._state, *inputs)
         b = int(np.shape(inputs[0])[0])
         bucket = bucket_batch(b, self.buckets)
-        padded = tuple(self._owned_padded(x, bucket, device) for x in inputs)
+        target = self._batch_sharding if device is None else device
+        padded = tuple(self._owned_padded(x, bucket, target) for x in inputs)
         STATS.jit_calls += 1
         with self._ctr.lock:
             self.jit_calls += 1
             rows = self._ctr.rows.setdefault((be, bucket), [0, 0])
             rows[0] += b
             rows[1] += bucket
-        state = self._state if device is None else self._state_for(device)
-        y = self._jit(state, padded, backend=be)
+        y = self._jit(self._state_for(device), padded, backend=be)
         return y if bucket == b else y[:b]
 
+    def lower(self, *inputs, backend: str | None = None):
+        """Ahead-of-time lowering of the jitted forward for inputs already
+        at a bucket size (arrays or ``jax.ShapeDtypeStruct``s), without
+        running it: ``.compile().as_text()`` shows what the device executes
+        (a compiled Pallas kernel appears as ``tpu_custom_call``)."""
+        be = self.backend if backend is None else backend
+        return self._jit.lower(self._state_for(None), tuple(inputs), backend=be)
+
     def _state_for(self, device):
-        """The bank-state replica committed to ``device`` (built once per
-        device). Placed calls pass the replica so every operand of the
-        jitted forward lives on one device — mixed-device arguments are a
-        jit error, and replicating KiB-scale LUT tables once is far cheaper
-        than shipping them per call."""
+        """The bank state a call reads: with ``device=None`` the plan's own
+        (replicated on the mesh in SHARDED mode), otherwise the replica
+        committed to ``device`` (built once per device). Placed calls pass
+        the replica so every operand of the jitted forward lives on one
+        device — mixed-device arguments are a jit error, and replicating
+        KiB-scale LUT tables once is far cheaper than shipping them per
+        call."""
+        if device is None:
+            return self._call_state
         with self._replica_lock:
             st = self._replicas.get(device)
         if st is None:
@@ -759,39 +778,39 @@ class ExecutionPlan:
         return st
 
     @staticmethod
-    def _owned_padded(x: jax.Array, bucket: int, device=None) -> jax.Array:
+    def _owned_padded(x, bucket: int, target=None) -> jax.Array:
         """A plan-OWNED buffer at the bucket size — safe to donate.
 
-        Padding (and host→device transfer of non-jax inputs) always yields a
-        fresh buffer; the one case where the caller's array would otherwise
-        flow straight through — a jax array already at its bucket size — is
-        defensively copied, because a donated buffer is deleted after the
-        call. The copy is one batch-sized memcpy, orders of magnitude below
-        the per-call budget it buys donation for.
+        Host inputs are padded on the host and transferred once, which
+        yields a fresh buffer. A jax array is padded where it lives (a fresh
+        buffer too); one already at its bucket size is copied, because a
+        donated buffer is deleted after the call.
 
-        With ``device`` set (PLACED mode) the buffer is committed to that
-        device first — the pad/copy then executes there, so the jitted call
-        sees same-device operands and runs entirely on its stream.
+        ``target`` is where the jitted call reads its inputs: a device
+        (PLACED mode — the call then runs entirely on that device's
+        stream), the batch sharding of the mesh (SHARDED mode — each device
+        receives only its rows), or ``None`` (the default device).
         """
+        b = np.shape(x)[0]
+        pad = [(0, bucket - b)] + [(0, 0)] * (np.ndim(x) - 1)
         if not isinstance(x, jax.Array):
             x = np.asarray(x)
-            x = jnp.asarray(x) if device is None else jax.device_put(x, device)
-            owned = True                   # fresh device buffer: plan-owned
-        elif device is not None and device not in x.devices():
-            x = jax.device_put(x, device)  # cross-device copy: plan-owned
-            owned = True
-        else:
-            owned = False
-        b = x.shape[0]
-        if b != bucket:
-            pad = [(0, bucket - b)] + [(0, 0)] * (x.ndim - 1)
-            return jnp.pad(x, pad)
+            if b != bucket:
+                x = np.pad(x, pad)
+            return jnp.asarray(x) if target is None else jax.device_put(x, target)
+        owned = b != bucket
+        if owned:
+            x = jnp.pad(x, pad)
+        if target is not None:
+            # may_alias only for our own padded buffer: a caller's array is
+            # always copied, never donated
+            return jax.device_put(x, target, may_alias=owned)
         return x if owned else x.copy()
 
     def _lut_cell_stats(self) -> tuple[int, int]:
         """(useful, dispatched) LUT cells across the plan's kernel steps.
 
-        A fused stack dispatches its whole padded ``[L, Kmax, C, Nmax]``
+        A fused stack dispatches its whole padded ``[L, W, Kmax·C]``
         slab per call; only the member banks' true ``K·C·N`` cells carry
         signal. Standalone banks contribute their true cells to BOTH terms,
         so the ratio weights fused padding by its real share of the plan's
@@ -800,7 +819,7 @@ class ExecutionPlan:
         useful = dispatched = 0
         for s in self.fused_stacks:
             c = s.banks[0].layer.num_centroids
-            dispatched += len(s.banks) * max(s.ks) * c * int(s.lut.shape[-1])
+            dispatched += len(s.banks) * max(s.ks) * c * s.width
             useful += sum(b.layer.num_groups * c * b.layer.out_features
                           for b in s.banks)
         for b in self.banks:
@@ -847,14 +866,14 @@ class ExecutionPlan:
                 f"group{g}": {
                     "layers": len(s.banks),
                     "kmax": max(s.ks),
-                    "nmax": int(s.lut.shape[-1]),
+                    "width": s.width,
                     "frac": round(
                         1.0 - sum(b.layer.num_groups
                                   * b.layer.num_centroids
                                   * b.layer.out_features for b in s.banks)
                         / (len(s.banks) * max(s.ks)
                            * s.banks[0].layer.num_centroids
-                           * int(s.lut.shape[-1])), 4),
+                           * s.width), 4),
                 }
                 for g, s in enumerate(self.fused_stacks)
             },
@@ -1073,7 +1092,7 @@ def build_plan(
         fuse_nmax_cap: bounds each fused group's padded output width
             (:data:`DEFAULT_FUSE_NMAX_CAP` = 2048 columns; ``None``
             disables the cap) so one wide bank cannot balloon a narrow
-            stack's padded ``[L, Kmax, C, Nmax]`` VMEM footprint;
+            stack's padded ``[L, W, Kmax·C]`` VMEM footprint;
             uniformly-wide runs still fuse above the cap because they
             add no padding. Both fusion knobs participate in
             ``plan_for``'s memo key, so fused and unfused plans of one

@@ -1,15 +1,19 @@
 """Pallas TPU kernel: fused fuzzy-index + LUT accumulate (Pegasus Map+SumReduce).
 
-TPU-native realization of the paper's table lookup (DESIGN.md §2):
+TPU-native realization of the paper's table lookup:
 
   * The clustering-tree descent is *gather-free*: feature selection becomes a
-    tiny one-hot einsum (``feat_oh`` is precomputed offline), node selection
+    tiny one-hot matmul (the one-hot is precomputed offline), node selection
     a one-hot reduction — every step is VPU compare/select or MXU matmul, so
     the whole "fuzzy match" is branchless and systolic-friendly.
   * The Map (leaf→row lookup) + SumReduce (Σ over groups) pair is ONE MXU
     matmul: ``onehot(leaf): [Tt, Kt·C] @ LUT-block: [Kt·C, Nt]`` — the same
     primitive-fusion insight as the paper's, re-expressed for a systolic
     array instead of a MAT stage.
+
+Every matmul that carries a value (an activation or a LUT entry) runs at
+``Precision.HIGHEST``: a one-hot select must return the activation exactly,
+or a tree decision next to its threshold flips.
 
 Single-bank tiling (BlockSpec, all VMEM):
   grid = (T/Tt, N/Nt, K/Kt);   K innermost → output block accumulates.
@@ -19,33 +23,30 @@ Single-bank tiling (BlockSpec, all VMEM):
     lut      [K, C, N]   → block (Kt, C, Nt)      index (k, 0, j)
     out      [T, N]      → block (Tt, Nt)         index (i, j)
 
-VMEM working set ≈ Tt·Kt·v + Kt·I·v + Kt·C·Nt + Tt·Nt floats.
-Defaults (Tt=256, Kt=128, Nt=256, C=16, v=8): ≈ 2.6 MB ≪ 128 MB VMEM, and
-the MXU contraction dims (Kt·C = 2048, Nt = 256) are 128-aligned.
-
 Stacked-layer variant (:func:`fuzzy_lut_stack_pallas` — Cross-bank Primitive
 Fusion): a compatible run of L banks executes as ONE kernel invocation. The
 grid tiles ONLY the batch; every per-layer operand rides whole (stacked along
-a leading L axis) so the inter-bank activation never leaves VMEM — the
-re-partition (``[Tt, N] → [Tt, K, v]``), bias add, and (q8 path) in-register
-dequantization all happen inside the per-layer loop:
+a leading L axis), so the inter-bank activation never leaves VMEM. The stack
+works in a *transposed, lane-dense* layout that :func:`stack_layout` builds
+once at plan build: flows on lanes, features and tree nodes on sublanes. In
+that layout the between-bank re-partition is free — the next bank's
+block-diagonal one-hot select reads its groups straight out of the previous
+bank's output rows — and the per-group tree walk splits only the sublane
+axis (``[K·C, Tt] → [K, C, Tt]``), which Mosaic lays out tile for tile:
 
   grid = (T/Tt,)
-    x        [T, K₀, v]        → block (Tt, K₀, v)       index (i, 0, 0)
-    feat_oh  [L, Kmax, I, v]   → whole                    index 0
-    thr      [L, Kmax, I]      → whole                    index 0
-    lut      [L, Kmax, C, Nmax]→ whole                    index 0
-    bias     [L, Nmax]         → whole                    index 0
-    out      [T, n_out]        → block (Tt, n_out)        index (i, 0)
+    x^T      [W, T]          → block (W, Tt)           index (0, i)
+    sel      [L, K·C, W]     → whole   one-hot: node (k, n) reads feature k·v+f
+    thr      [L, K·C, 1]     → whole   +inf on the padding node n = C-1
+    lut^T    [L, W, K·C]     → whole   f32, or int8 with scales [L, 1, K·C]
+    bias     [L, W, 1]       → whole
+    out^T    [W, T]          → block (W, Tt)           index (0, i)
 
-Banks are padded to the group's (Kmax, Nmax) at PLAN BUILD (zero LUT rows
-and +inf thresholds: padded groups descend to leaf 0 and contribute 0), so
-warm calls pad nothing but the batch. VMEM working set ≈
-Tt·Kmax·v·2 (x + repartitioned h) + L·Kmax·I·(v+1) + L·Kmax·C·Nmax (LUT)
-+ L·Nmax + Tt·Nmax floats. The MLP-B shape (L=4, Kmax=16, I=63, C=64,
-Nmax=32, v=2, Tt=1024) is ≈ 1.0 MB — the LUT stack and the Tt·Nmax tiles
-dominate, so cap L (or shrink Tt via ``block_t``) when their sum approaches
-the VMEM budget.
+``W`` (the activation width, a multiple of 8) covers the stack's widest
+input and output. Padded groups hold +inf thresholds and zero LUT rows, so
+they descend to leaf 0 and contribute nothing. The batch tile is derived
+from the bank shape (:func:`stack_block_t`) so the ``[K·C, Tt]`` descent
+intermediates stay ≈1 MiB each.
 
 Both entry points raise ``ValueError`` (never ``assert``, which dies
 silently under ``python -O``) when a dimension is not block-divisible, so
@@ -62,14 +63,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["default_interpret", "fuzzy_lut_kernel", "fuzzy_lut_pallas",
-           "fuzzy_lut_stack_pallas", "resolve_strategy"]
+           "fuzzy_lut_stack_pallas", "resolve_strategy", "stack_block_t",
+           "stack_layout"]
 
-# Batch tile of the stacked-layer kernel. Larger than the single-bank default
-# (256): the stack's grid has no N/K axes, so the only per-tile overhead is
-# the interpreter's operand slicing — fewer, fatter tiles win on CPU (A/B
-# swept 256/512/1024 at batch 1024), and the VMEM working set stays ≈1 MB
-# for every shipped bank geometry (see module docstring).
-STACK_BLOCK_T = 1024
+# Byte budget of ONE [K·C, Tt] f32 descent intermediate of the stacked
+# kernel (values, bits, one-hots); the batch tile follows from it.
+STACK_TILE_BYTES = 1 << 20
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def default_interpret() -> bool:
@@ -85,11 +86,7 @@ def default_interpret() -> bool:
 
 
 def _tpu_compiler_params(dimension_semantics: tuple[str, ...]):
-    """dimension_semantics plumbing across pallas API versions."""
-    try:
-        return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
-    except (AttributeError, TypeError):  # older API
-        return dict(mosaic=dict(dimension_semantics=dimension_semantics))
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
 
 def _tree_leaf(x, feat_oh, thr, *, depth: int, strategy: str):
@@ -130,6 +127,7 @@ def _tree_leaf(x, feat_oh, thr, *, depth: int, strategy: str):
         feat_oh,
         # contract v; batch over k
         dimension_numbers=(((2,), (2,)), ((1,), (0,))),
+        precision=_HIGHEST,
         preferred_element_type=jnp.float32,
     )                                             # [Kt, Tt, I]
     vals = vals.transpose(1, 0, 2)                # [Tt, Kt, I]
@@ -171,6 +169,7 @@ def _lut_contrib(leaf, lut, *, strategy: str, scale=None):
         onehot.reshape(tt, kt * c),
         lut.reshape(kt * c, -1),
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=_HIGHEST,
         preferred_element_type=jnp.float32,
     )                                             # [Tt, Nt]
 
@@ -216,11 +215,19 @@ def resolve_strategy(strategy: str, interpret: bool) -> str:
     """``auto`` → ``lookup`` under the interpreter (CPU executes the one-hot
     matmul's C× redundant work serially), ``mxu`` on compiled TPU (systolic
     arrays eat dense matmuls; gathers don't vectorize). Both strategies are
-    semantics-identical and parity-tested against each other."""
+    semantics-identical and parity-tested against each other. ``lookup``
+    exists only in interpret mode: Mosaic cannot compile its gathers, so a
+    compiled ``lookup`` request is refused here rather than deep in the
+    compiler."""
     if strategy == "auto":
         return "lookup" if interpret else "mxu"
     if strategy not in ("mxu", "lookup"):
         raise ValueError(f"unknown strategy {strategy!r}; expected auto|mxu|lookup")
+    if strategy == "lookup" and not interpret:
+        raise ValueError(
+            "strategy 'lookup' runs only in Pallas interpret mode (Mosaic "
+            "cannot compile its gathers); use 'mxu' or 'auto' with "
+            "interpret=False")
     return strategy
 
 
@@ -280,50 +287,152 @@ def fuzzy_lut_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _stack_layers(h, feat_oh, thr, lut, bias, scales, *, depth: int,
-                  ks: tuple[int, ...], v: int, strategy: str):
-    """Run the whole bank stack over one batch tile, all in registers/VMEM.
+def stack_layout(feat_oh, thr, lut, bias, scales=None) -> tuple:
+    """Plan-build layout prep: group-major stacked operands → the stacked
+    kernel's transposed, lane-dense layout (module docstring).
 
-    ``h``: [Tt, K₀, v] activations; stacked operands carry a leading L axis
-    (see module docstring). Between layers the activation is re-partitioned
-    ``[Tt, N] → [Tt, ks[l+1], v]`` and zero-padded back to Kmax — padded
-    groups hold +inf thresholds and zero LUT rows, so they descend to leaf 0
-    and contribute nothing. ``scales`` (q8 path) dequantizes each layer's
-    int8 LUT in-VMEM via the per-group factors; ``None`` on the fp path.
+    Takes ``feat_oh [L, Kmax, I, v]``, ``thr [L, Kmax, I]``,
+    ``lut [L, Kmax, C, Nmax]`` (f32 or int8), ``bias [L, Nmax]`` and, for
+    the int8 stack, ``scales [L, Kmax]``. Returns ``(sel, thr, lut_t,
+    bias)`` — plus the scales pre-broadcast to ``[L, 1, Kmax·C]`` when
+    given — with ``W = round8(max(Kmax·v, Nmax))`` activation rows.
     """
-    nlayers, kmax = lut.shape[0], lut.shape[1]
-    tt = h.shape[0]
-    if h.shape[1] < kmax:
-        h = jnp.pad(h, ((0, 0), (0, kmax - h.shape[1]), (0, 0)))
-    y = None
-    for l in range(nlayers):
-        leaf = _tree_leaf(h, feat_oh[l].astype(jnp.float32),
-                          thr[l].astype(jnp.float32),
-                          depth=depth, strategy=strategy)
-        tab = lut[l].astype(jnp.float32)
-        if scales is not None:
-            # q8 dequant in-VMEM, scales folded into the TABLE (exact: the
-            # factor is constant per group) — K·C·N multiplies once per tile
-            # instead of T·K·N on every gathered row
-            tab = tab * scales[l].astype(jnp.float32)[:, None, None]
-        y = _lut_contrib(leaf, tab, strategy=strategy)
-        y = y + bias[l].astype(jnp.float32)
-        if l + 1 < nlayers:
-            nk = ks[l + 1]
-            h = y[:, : nk * v].reshape(tt, nk, v)
-            if nk < kmax:
-                h = jnp.pad(h, ((0, 0), (0, kmax - nk), (0, 0)))
-    return y
+    nl, kmax, n_int, v = feat_oh.shape
+    c, nmax = lut.shape[2], lut.shape[3]
+    kc = kmax * c
+    w = -(-max(kmax * v, nmax) // 8) * 8
+    # node axis padded to C: the extra node n = C-1 is never visited
+    fo = jnp.pad(feat_oh.astype(jnp.float32),
+                 ((0, 0), (0, 0), (0, c - n_int), (0, 0)))
+    # block-diagonal select: row k·C+n picks column k·v+f
+    sel = jnp.einsum("lknf,kj->lknjf", fo, jnp.eye(kmax, dtype=jnp.float32))
+    sel = jnp.pad(sel.reshape(nl, kc, kmax * v),
+                  ((0, 0), (0, 0), (0, w - kmax * v)))
+    thr_col = jnp.pad(thr.astype(jnp.float32),
+                      ((0, 0), (0, 0), (0, c - n_int)),
+                      constant_values=jnp.inf).reshape(nl, kc, 1)
+    lut_t = jnp.pad(lut.reshape(nl, kc, nmax).transpose(0, 2, 1),
+                    ((0, 0), (0, w - nmax), (0, 0)))
+    bias_col = jnp.pad(bias.astype(jnp.float32),
+                       ((0, 0), (0, w - nmax)))[:, :, None]
+    out = (sel, thr_col, lut_t, bias_col)
+    if scales is not None:
+        out += (jnp.repeat(scales.astype(jnp.float32), c, axis=1)[:, None, :],)
+    return out
 
 
-def fuzzy_lut_stack_kernel(x_ref, feat_oh_ref, thr_ref, lut_ref, bias_ref,
-                           out_ref, *, depth: int, ks: tuple[int, ...],
-                           v: int, n_out: int, strategy: str):
+def stack_block_t(kc: int) -> int:
+    """Batch tile of the stacked kernel for ``K·C`` tree nodes per layer:
+    the largest power of two in [128, 1024] whose ``[K·C, Tt]`` f32
+    descent intermediates fit :data:`STACK_TILE_BYTES`."""
+    bt = 1024
+    while bt > 128 and kc * bt * 4 > STACK_TILE_BYTES:
+        bt //= 2
+    return bt
+
+
+def _stack_layer_mxu(h, sel, thr, tab, *, depth: int):
+    """One bank in the transposed layout, gather-free: ``h [W, Tt]`` →
+    ``[W, Tt]`` contributions (bias not yet added)."""
+    kc, tt = sel.shape[0], h.shape[1]
+    c = 1 << depth
+    k = kc // c
+    vals = jax.lax.dot_general(                    # [K·C, Tt]: x at each node
+        sel, h, (((1,), (0,)), ((), ())), precision=_HIGHEST,
+        preferred_element_type=jnp.float32)
+    bits = (vals > thr).astype(jnp.int32).reshape(k, c, tt)
+    iota = jax.lax.broadcasted_iota(jnp.int32, (k, c, tt), 1)
+    node = jnp.zeros((k, 1, tt), jnp.int32)
+    for _ in range(depth):
+        bit = jnp.sum(jnp.where(iota == node, bits, 0), axis=1, keepdims=True)
+        node = 2 * node + 1 + bit
+    onehot = (iota == node - (c - 1)).astype(jnp.float32).reshape(kc, tt)
+    return jax.lax.dot_general(                    # [W, Tt]: Σ_k lut[k, leaf]
+        tab, onehot, (((1,), (0,)), ((), ())), precision=_HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def _stack_layer_lookup(h, sel, thr, tab, *, depth: int):
+    """Interpreter form of :func:`_stack_layer_mxu`: walk only the visited
+    nodes with gathers, then gather-sum the leaf columns."""
+    kc, tt = sel.shape[0], h.shape[1]
+    c = 1 << depth
+    k = kc // c
+    feat = jnp.argmax(sel, axis=1).astype(jnp.int32)          # [K·C]
+    thr = thr[:, 0]
+    base = (jnp.arange(k, dtype=jnp.int32) * c)[:, None]      # [K, 1]
+    node = jnp.zeros((k, tt), jnp.int32)
+    for _ in range(depth):
+        idx = node + base
+        val = jnp.take_along_axis(h, jnp.take(feat, idx), axis=0)
+        node = 2 * node + 1 + (val > jnp.take(thr, idx)).astype(jnp.int32)
+    rows = jnp.take(tab, node - (c - 1) + base, axis=1)        # [W, K, Tt]
+    # group by group, so no row's sum depends on the tile width
+    return sum(rows[:, g] for g in range(k))                   # [W, Tt]
+
+
+def _stack_layers(h, sel_ref, thr_ref, lut_ref, bias_ref, scale_ref, *,
+                  depth: int, strategy: str):
+    """Run the whole bank stack over one batch tile, all in VMEM: each
+    bank's output rows are the next bank's input rows. ``scale_ref`` (q8
+    path) dequantizes each layer's int8 table in-VMEM — the per-group
+    factor is constant within a group, so folding it into the table is
+    exact; ``None`` on the fp path."""
+    layer = _stack_layer_mxu if strategy == "mxu" else _stack_layer_lookup
+    for l in range(sel_ref.shape[0]):
+        tab = lut_ref[l].astype(jnp.float32)               # [W, K·C]
+        if scale_ref is not None:
+            tab = tab * scale_ref[l]                       # [1, K·C] rows
+        h = layer(h, sel_ref[l], thr_ref[l], tab, depth=depth) + bias_ref[l]
+    return h
+
+
+def fuzzy_lut_stack_kernel(x_ref, sel_ref, thr_ref, lut_ref, bias_ref,
+                           out_ref, *, depth: int, strategy: str):
     """One batch tile through ALL L fused banks (fp32 LUT stack)."""
-    y = _stack_layers(
-        x_ref[...].astype(jnp.float32), feat_oh_ref, thr_ref, lut_ref,
-        bias_ref, None, depth=depth, ks=ks, v=v, strategy=strategy)
-    out_ref[...] = y[:, :n_out]
+    out_ref[...] = _stack_layers(
+        x_ref[...], sel_ref, thr_ref, lut_ref, bias_ref, None,
+        depth=depth, strategy=strategy)
+
+
+def _whole_block(ndim: int):
+    """Index map of an operand that rides whole in every grid step."""
+    return lambda i: (0,) * ndim
+
+
+def stack_call(kernel, x, operands, *, depth: int, ks: tuple[int, ...],
+               n_out: int, block_t: int | None, interpret: bool | None,
+               strategy: str, where: str) -> jax.Array:
+    """Shared launcher of the fp32 and int8 stacked kernels: validates the
+    stack against ``ks``, transposes the ``[T, ks[0]·v]`` batch into the
+    ``[W, T]`` layout, tiles the batch, and returns ``[T, n_out]``."""
+    if interpret is None:
+        interpret = default_interpret()
+    strategy = resolve_strategy(strategy, interpret)
+    sel = operands[0]
+    nlayers, kc, w = sel.shape
+    if len(ks) != nlayers:
+        raise ValueError(f"ks has {len(ks)} entries for {nlayers} stacked layers")
+    t, d0 = x.shape
+    if d0 > w or kc % (1 << depth) or max(ks) > kc >> depth:
+        raise ValueError(
+            f"{where}: x carries {d0} features and ks={ks}, but the stack "
+            f"holds {kc} tree nodes over {w} activation rows per layer")
+    bt = min(stack_block_t(kc) if block_t is None else block_t, t)
+    _check_divisible(where, T=(t, bt))
+
+    whole = [pl.BlockSpec(op.shape, _whole_block(op.ndim)) for op in operands]
+    xt = jnp.pad(x.astype(jnp.float32).T, ((0, w - d0), (0, 0)))
+    out = pl.pallas_call(
+        functools.partial(kernel, depth=depth, strategy=strategy),
+        grid=(t // bt,),
+        in_specs=[pl.BlockSpec((w, bt), lambda i: (0, i))] + whole,
+        out_specs=pl.BlockSpec((w, bt), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((w, t), jnp.float32),
+        compiler_params=_tpu_compiler_params(("parallel",)),
+        interpret=interpret,
+    )(xt, *operands)
+    return out[:n_out].T
 
 
 @functools.partial(
@@ -332,16 +441,16 @@ def fuzzy_lut_stack_kernel(x_ref, feat_oh_ref, thr_ref, lut_ref, bias_ref,
                      "strategy"),
 )
 def fuzzy_lut_stack_pallas(
-    x: jax.Array,          # [T, K₀, v]
-    feat_oh: jax.Array,    # [L, Kmax, I, v]
-    thr: jax.Array,        # [L, Kmax, I]
-    lut: jax.Array,        # [L, Kmax, C, Nmax] f32
-    bias: jax.Array,       # [L, Nmax] (zeros where a bank has no bias)
+    x: jax.Array,          # [T, ks[0]·v]
+    sel: jax.Array,        # [L, K·C, W] one-hot feature select (stack_layout)
+    thr: jax.Array,        # [L, K·C, 1]
+    lut: jax.Array,        # [L, W, K·C] f32, transposed
+    bias: jax.Array,       # [L, W, 1] (zeros where a bank has no bias)
     *,
     depth: int,
-    ks: tuple[int, ...],   # true group count per layer (≤ Kmax)
-    n_out: int,            # true out_features of the LAST layer (≤ Nmax)
-    block_t: int = STACK_BLOCK_T,
+    ks: tuple[int, ...],   # true group count per layer (≤ K)
+    n_out: int,            # true out_features of the LAST layer (≤ W)
+    block_t: int | None = None,
     interpret: bool | None = None,
     strategy: str = "auto",
 ) -> jax.Array:
@@ -349,37 +458,12 @@ def fuzzy_lut_stack_pallas(
 
     Returns ``[T, n_out]`` f32 — bias already applied (it must be: every
     non-final layer's bias feeds the next layer's tree descent in-VMEM).
-    Operand stacks must be pre-padded to (Kmax, Nmax) at plan build; only
-    the batch axis is tiled, so T is the only dim with a divisibility
-    constraint (``ValueError`` otherwise — catchable, see module docstring).
+    Operands come from :func:`stack_layout` at plan build; only the batch
+    axis is tiled (``block_t`` defaults to :func:`stack_block_t`), so T is
+    the only dim with a divisibility constraint (``ValueError`` otherwise —
+    catchable, see module docstring).
     """
-    if interpret is None:
-        interpret = default_interpret()
-    strategy = resolve_strategy(strategy, interpret)
-    t, k0, v = x.shape
-    nlayers, kmax, c, nmax = lut.shape
-    n_internal = thr.shape[2]
-    if len(ks) != nlayers:
-        raise ValueError(f"ks has {len(ks)} entries for {nlayers} stacked layers")
-    if k0 != ks[0]:
-        raise ValueError(f"x carries K={k0} groups; ks[0]={ks[0]}")
-    bt = min(block_t, t)
-    _check_divisible("fuzzy_lut_stack_pallas", T=(t, bt))
-
-    grid = (t // bt,)
-    return pl.pallas_call(
-        functools.partial(fuzzy_lut_stack_kernel, depth=depth, ks=ks, v=v,
-                          n_out=n_out, strategy=strategy),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bt, k0, v), lambda i: (i, 0, 0)),
-            pl.BlockSpec((nlayers, kmax, n_internal, v), lambda i: (0, 0, 0, 0)),
-            pl.BlockSpec((nlayers, kmax, n_internal), lambda i: (0, 0, 0)),
-            pl.BlockSpec((nlayers, kmax, c, nmax), lambda i: (0, 0, 0, 0)),
-            pl.BlockSpec((nlayers, nmax), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bt, n_out), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((t, n_out), jnp.float32),
-        compiler_params=_tpu_compiler_params(("arbitrary",)),
-        interpret=interpret,
-    )(x, feat_oh, thr, lut, bias)
+    return stack_call(fuzzy_lut_stack_kernel, x, (sel, thr, lut, bias),
+                      depth=depth, ks=ks, n_out=n_out, block_t=block_t,
+                      interpret=interpret, strategy=strategy,
+                      where="fuzzy_lut_stack_pallas")

@@ -1,4 +1,4 @@
-"""int8-LUT variant of the fused fuzzy-LUT kernel (beyond-paper §Perf D4).
+"""int8-LUT variant of the fused fuzzy-LUT kernel (beyond the paper).
 
 LUT rows are stored int8 with one f32 scale per partition group; the scale
 is folded into the one-hot BEFORE the MXU matmul (exact — the matmul sums
@@ -8,7 +8,11 @@ over (group, centroid) and the scale is constant within a group):
 
 Wire effect at decode: LUT bytes halve vs bf16; with v=16, C=16 the total
 weight-byte cost is 0.5·(C/v)=0.5× the dense bf16 weights — the decode
-memory-roofline lever recorded in EXPERIMENTS.md §Perf D4.
+memory-roofline lever.
+
+The stacked variant stores the scales already broadcast per centroid
+(``[L, 1, K·C]``, :func:`repro.kernels.fuzzy_lut.kernel.stack_layout`) and
+folds them into each layer's table in VMEM.
 """
 
 from __future__ import annotations
@@ -17,11 +21,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from .kernel import (
-    STACK_BLOCK_T,
     _check_divisible,
     _lut_contrib,
     _stack_layers,
@@ -29,6 +31,7 @@ from .kernel import (
     _tree_leaf,
     default_interpret,
     resolve_strategy,
+    stack_call,
 )
 
 __all__ = ["quantize_lut_int8", "fuzzy_lut_q8_pallas", "fuzzy_lut_q8_ref",
@@ -109,65 +112,38 @@ def fuzzy_lut_q8_pallas(
     )(x, feat_oh, thresholds, lut_q8, scales)
 
 
-def _stack_q8_kernel(x_ref, feat_oh_ref, thr_ref, lut_ref, scale_ref,
-                     bias_ref, out_ref, *, depth, ks, v, n_out, strategy):
+def _stack_q8_kernel(x_ref, sel_ref, thr_ref, lut_ref, scale_ref, bias_ref,
+                     out_ref, *, depth, strategy):
     """One batch tile through ALL L fused banks; int8 LUT stack dequantized
-    in-VMEM via the per-(layer, group) scale factors."""
-    y = _stack_layers(
-        x_ref[...].astype(jnp.float32), feat_oh_ref, thr_ref, lut_ref,
-        bias_ref, scale_ref, depth=depth, ks=ks, v=v, strategy=strategy)
-    out_ref[...] = y[:, :n_out]
+    in-VMEM via the pre-broadcast per-(layer, group) scale rows."""
+    out_ref[...] = _stack_layers(
+        x_ref[...], sel_ref, thr_ref, lut_ref, bias_ref, scale_ref,
+        depth=depth, strategy=strategy)
 
 
 @functools.partial(
     jax.jit, static_argnames=("depth", "ks", "n_out", "block_t", "interpret",
                               "strategy"))
 def fuzzy_lut_stack_q8_pallas(
-    x,            # [T, K₀, v]
-    feat_oh,      # [L, Kmax, I, v]
-    thr,          # [L, Kmax, I]
-    lut_q8,       # [L, Kmax, C, Nmax] int8
-    scales,       # [L, Kmax] f32 per-(layer, group) dequant factors
-    bias,         # [L, Nmax]
+    x,            # [T, ks[0]·v]
+    sel,          # [L, K·C, W] one-hot feature select (stack_layout)
+    thr,          # [L, K·C, 1]
+    lut_q8,       # [L, W, K·C] int8, transposed
+    scales,       # [L, 1, K·C] f32 dequant factors, broadcast per centroid
+    bias,         # [L, W, 1]
     *,
     depth: int,
     ks: tuple[int, ...],
     n_out: int,
-    block_t: int = STACK_BLOCK_T,
+    block_t: int | None = None,
     interpret: bool | None = None,
     strategy: str = "auto",
 ):
     """int8 stacked-layer kernel: the fused counterpart of
-    :func:`fuzzy_lut_q8_pallas` — LUT bytes stay halved in HBM AND the
+    :func:`fuzzy_lut_q8_pallas` — LUT bytes stay quartered in HBM AND the
     dequantized rows never leave VMEM between banks. Contract mirrors
     :func:`repro.kernels.fuzzy_lut.kernel.fuzzy_lut_stack_pallas`."""
-    if interpret is None:
-        interpret = default_interpret()
-    strategy = resolve_strategy(strategy, interpret)
-    t, k0, v = x.shape
-    nlayers, kmax, c, nmax = lut_q8.shape
-    n_internal = thr.shape[2]
-    if len(ks) != nlayers:
-        raise ValueError(f"ks has {len(ks)} entries for {nlayers} stacked layers")
-    if k0 != ks[0]:
-        raise ValueError(f"x carries K={k0} groups; ks[0]={ks[0]}")
-    bt = min(block_t, t)
-    _check_divisible("fuzzy_lut_stack_q8_pallas", T=(t, bt))
-
-    return pl.pallas_call(
-        functools.partial(_stack_q8_kernel, depth=depth, ks=ks, v=v,
-                          n_out=n_out, strategy=strategy),
-        grid=(t // bt,),
-        in_specs=[
-            pl.BlockSpec((bt, k0, v), lambda i: (i, 0, 0)),
-            pl.BlockSpec((nlayers, kmax, n_internal, v), lambda i: (0, 0, 0, 0)),
-            pl.BlockSpec((nlayers, kmax, n_internal), lambda i: (0, 0, 0)),
-            pl.BlockSpec((nlayers, kmax, c, nmax), lambda i: (0, 0, 0, 0)),
-            pl.BlockSpec((nlayers, kmax), lambda i: (0, 0)),
-            pl.BlockSpec((nlayers, nmax), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bt, n_out), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((t, n_out), jnp.float32),
-        compiler_params=_tpu_compiler_params(("arbitrary",)),
-        interpret=interpret,
-    )(x, feat_oh, thr, lut_q8, scales, bias)
+    return stack_call(_stack_q8_kernel, x, (sel, thr, lut_q8, scales, bias),
+                      depth=depth, ks=ks, n_out=n_out, block_t=block_t,
+                      interpret=interpret, strategy=strategy,
+                      where="fuzzy_lut_stack_q8_pallas")

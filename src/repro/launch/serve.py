@@ -55,6 +55,7 @@ from repro.models.transformer import (
     decode_step, forward_train, init_decode_state, init_model,
 )
 
+from .compile_cache import enable_compile_cache
 from .devices import DeviceStreamPool
 from .health import CLOSED, CircuitBreaker
 from .mesh import batch_specs, decode_state_specs, named, param_specs
@@ -67,7 +68,8 @@ __all__ = ["make_serve_step", "make_prefill_step", "Server", "PegasusServer",
            "MultiModelServer", "AsyncMultiModelServer", "PartialDrainError",
            "QueueFullError", "DeadlineExceededError", "PRIORITY_WEIGHTS",
            "InferRequest", "InferResult", "DeviceStreamPool",
-           "ServerStoppedError", "PoisonedRequestError", "FALLBACK_BACKEND"]
+           "ServerStoppedError", "PoisonedRequestError", "FALLBACK_BACKEND",
+           "mlp_b_server"]
 
 # The bottom rung of the backend fallback ladder: plain jnp gather — no
 # Pallas kernel, no one-hot matmul structure, the least machinery that can
@@ -360,16 +362,20 @@ class PegasusServer:
                 for r, o, n in zip(reqs, split, sizes)]
 
 
-def _coalesce(requests) -> tuple[list, list[int], int]:
+def _coalesce(requests, *, host: bool = False) -> tuple[list, list[int], int]:
     """Normalize a request list (arrays or input tuples, each with a leading
-    batch dim) into per-input concatenations + per-request sizes."""
+    batch dim) into per-input concatenations + per-request sizes. ``host``
+    keeps host inputs on the host (a device pool places each chunk on its
+    own device; a jnp concatenation would first route it through the
+    default device)."""
     reqs = [tuple(r) if isinstance(r, (tuple, list)) else (r,) for r in requests]
     sizes = [int(np.shape(r[0])[0]) for r in reqs]
+    xp = np if host else jnp
     if len(reqs) == 1:
-        cat = [r if isinstance(r, jax.Array) else jnp.asarray(r)
+        cat = [r if isinstance(r, jax.Array) else xp.asarray(r)
                for r in reqs[0]]
     else:
-        cat = [jnp.concatenate([jnp.asarray(r[i]) for r in reqs], axis=0)
+        cat = [xp.concatenate([xp.asarray(r[i]) for r in reqs], axis=0)
                for i in range(len(reqs[0]))]
     return cat, sizes, sum(sizes)
 
@@ -666,7 +672,11 @@ class MultiModelServer:
                  deadline_ms: float | None = None,
                  priority: str = "normal") -> int:
         self._tracked(name)
-        inputs = tuple(x if isinstance(x, jax.Array) else jnp.asarray(x)
+        # stage host inputs on the device now, off the dispatch thread —
+        # except in front of a device pool, whose streams each take their
+        # chunks straight from the host
+        xp = np if self._pool is not None else jnp
+        inputs = tuple(x if isinstance(x, jax.Array) else xp.asarray(x)
                        for x in inputs)
         return self._sched.submit(name, inputs, int(np.shape(inputs[0])[0]),
                                   future=future, timeout=timeout,
@@ -795,7 +805,8 @@ class MultiModelServer:
                         key = ("fallback_batches" if g["degraded"]
                                else "probe_batches")
                         h[key] += 1
-            cat, sizes, total = _coalesce([r.inputs for r in reqs])
+            cat, sizes, total = _coalesce([r.inputs for r in reqs],
+                                          host=self._pool is not None)
             chunks = bucket_chunks(total, plan.buckets, self.max_batch)
             outs, start = [], 0
             for size in chunks:
@@ -1459,31 +1470,42 @@ class AsyncMultiModelServer(MultiModelServer):
                 time.sleep(self._idle_wait)
 
 
-def _pegasus_demo(args) -> None:
-    """--pegasus: train a tiny MLP on synthetic traffic, compile one plan,
-    and serve request batches on the chosen backend."""
-    from repro.data.synthetic_traffic import make_dataset
-    from repro.nets.mlp import pegasusify_mlp, train_mlp
+def mlp_b_server(backends=("onehot",), *, seed: int = 0, **server_kw):
+    """The MLP-B serving recipe: the model of
+    :func:`repro.nets.mlp.mlp_b_recipe` registered once per backend, as
+    ``"mlp_b/<backend>"``, on an always-on :class:`AsyncMultiModelServer`
+    (``server_kw`` goes to its constructor: ``fuse``, ``devices``, ...).
+    Returns ``(server, dataset)``; the server is not started yet."""
+    from repro.nets.mlp import mlp_b_recipe
 
-    ds = make_dataset("peerrush", flows_per_class=120)
-    mlp = train_mlp(ds.train["stats"], ds.train["label"], ds.num_classes, steps=120)
-    banks = pegasusify_mlp(mlp, ds.train["stats"].astype(np.float32), refine_steps=0)
-    server = PegasusServer(banks, backend=args.backend, fuse=not args.no_fuse)
-    st0 = server.plan.compile_stats()
-    print(f"plan compiled in {server.plan_build_ms:.1f} ms "
-          f"({server.plan.num_banks} banks, {st0['fused_groups']} fused "
+    banks, ds = mlp_b_recipe(seed)
+    server = AsyncMultiModelServer(**server_kw)
+    for be in backends:
+        server.add_model(f"mlp_b/{be}", banks, backend=be)
+    return server, ds
+
+
+def _pegasus_demo(args) -> None:
+    """--pegasus: build MLP-B (:func:`mlp_b_server`) and serve request
+    batches on the chosen backend through the always-on async server."""
+    server, ds = mlp_b_server([args.backend], fuse=not args.no_fuse)
+    name = f"mlp_b/{args.backend}"
+    st0 = server.stats()["engine"]["models"][name]
+    print(f"plan compiled in {st0['plan_build_ms']:.1f} ms "
+          f"({st0['num_banks']} banks, {st0['fused_groups']} fused "
           f"groups covering {st0['fused_banks']} banks, backend={args.backend})")
     x = ds.test["stats"].astype(np.float32)
-    requests = [InferRequest("mlp", x[i : i + args.batch])
+    requests = [InferRequest(name, x[i : i + args.batch])
                 for i in range(0, min(len(x), 8 * args.batch), args.batch)]
-    server.serve(requests)  # warmup/compile
-    t0 = time.perf_counter()
-    results = server.serve(requests)
-    dt = time.perf_counter() - t0
+    with server:
+        server.serve(requests)  # warmup/compile
+        t0 = time.perf_counter()
+        results = server.serve(requests)   # outputs arrive as np arrays
+        dt = time.perf_counter() - t0
     flows = sum(r.flows for r in results)
     print(f"served {len(requests)} requests ({flows} flows) in {dt * 1e3:.1f} ms "
           f"→ {flows / dt:.0f} flows/s on backend={args.backend}")
-    st = server.stats()["engine"]
+    st = server.stats()["engine"]["models"][name]
     print(f"compile cache: {st['traces']} traces, {st['bucket_hits']} bucket "
           f"hits over {st['jit_calls']} jit calls; buckets={st['buckets']}")
 
@@ -1503,6 +1525,7 @@ def main():
                     help="disable cross-bank primitive fusion (A/B escape "
                          "hatch; fusion is the default)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.pegasus:
         _pegasus_demo(args)
         return

@@ -20,7 +20,8 @@ from repro.engine import plan_for
 
 from .common import train_classifier
 
-__all__ = ["MLPB", "init_mlp", "mlp_apply", "train_mlp", "pegasusify_mlp", "pegasus_mlp_apply"]
+__all__ = ["MLPB", "init_mlp", "mlp_apply", "mlp_b_recipe", "train_mlp",
+           "pegasusify_mlp", "pegasus_mlp_apply"]
 
 HIDDEN = 32
 
@@ -172,6 +173,24 @@ def pegasusify_mlp(
             refined.append(refine(layer, xb, tgt, steps=refine_steps))
         layers = refined
     return layers
+
+
+def mlp_b_recipe(seed: int = 0, *, flows_per_class: int = 1500,
+                 steps: int = 120):
+    """MLP-B at its published widths, served as users serve it: 16 8-bit
+    flow statistics, ``group_size=2``, ``depth=6`` (64 centroids), hidden
+    32, 4 banks, PeerRush's 3 classes. The dense teacher trains in-process
+    on the synthetic PeerRush flows, all drawn from ``seed``; nothing is read
+    from disk. Returns ``(banks, dataset)`` — the banks go to ``build_plan``
+    or a server's ``add_model``."""
+    from repro.data.synthetic_traffic import make_dataset
+
+    ds = make_dataset("peerrush", flows_per_class=flows_per_class, seed=seed)
+    teacher = train_mlp(ds.train["stats"], ds.train["label"], ds.num_classes,
+                        steps=steps, seed=seed)
+    banks = pegasusify_mlp(teacher, ds.train["stats"].astype(np.float32),
+                           refine_steps=0)
+    return banks, ds
 
 
 def pegasus_mlp_apply(

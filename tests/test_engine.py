@@ -864,19 +864,19 @@ def test_compile_stats_reports_pad_waste_and_fusion(ds):
 
 
 def test_compile_stats_folds_fused_operand_padding_into_pad_waste():
-    """Kernel-backend pad_waste must charge the fused stack's Kmax/Nmax
+    """Kernel-backend pad_waste must charge the fused stack's Kmax/width
     operand padding, not just batch filler; the fallback backends run on
     true-size tables and keep the batch-only number. Pinned on the
-    (12, 8, 8, 3) chain: ks=(6, 4, 4), C=8, ns=(8, 8, 3) →
-    useful = 6·8·8 + 4·8·8 + 4·8·3 = 736 LUT cells of the 3·6·8·8 = 1152
-    the stacked slab dispatches."""
+    (12, 8, 8, 3) chain: ks=(6, 4, 4), C=8, ns=(8, 8, 3), activation width
+    W = round8(max(6·2, 8)) = 16 → useful = 6·8·8 + 4·8·8 + 4·8·3 = 736 LUT
+    cells of the 3·6·8·16 = 2304 the stacked slab dispatches."""
     layers = _chain_banks(42, dims=(12, 8, 8, 3))
     plan = build_plan(layers)
     assert plan.fused_banks == 3
     st = plan.compile_stats()
     fused = st["pad_waste_fused"]["group0"]
-    assert (fused["layers"], fused["kmax"], fused["nmax"]) == (3, 6, 8)
-    assert fused["frac"] == round(1 - 736 / 1152, 4) == 0.3611
+    assert (fused["layers"], fused["kmax"], fused["width"]) == (3, 6, 16)
+    assert fused["frac"] == round(1 - 736 / 2304, 4) == 0.6806
 
     x = jnp.asarray(np.random.default_rng(1).normal(size=(11, 12)), jnp.float32)
     plan(x, backend="kernel")
@@ -885,7 +885,7 @@ def test_compile_stats_folds_fused_operand_padding_into_pad_waste():
     # gather dispatches per-bank true-size tables: batch filler only
     assert waste["gather@16"] == round(5 / 16, 4)
     # kernel dispatches the padded slab: batch filler × operand efficiency
-    assert waste["kernel@16"] == round(1 - (11 / 16) * (736 / 1152), 4)
+    assert waste["kernel@16"] == round(1 - (11 / 16) * (736 / 2304), 4)
     # a fully-unfused plan has no operand padding: backends agree again
     unfused = build_plan(layers, fuse=False)
     unfused(x, backend="kernel")
@@ -950,7 +950,7 @@ def test_ops_layout_memo_pads_static_operands_once():
 @pytest.mark.slow
 def test_fuse_nmax_cap_splits_ballooning_groups():
     """Satellite: one wide bank must not balloon a narrow stack's padded
-    [L, Kmax, C, Nmax] footprint — the run splits at the cap (the wide bank
+    [L, W, Kmax·C] footprint — the run splits at the cap (the wide bank
     stands alone), narrow neighbors still fuse, and outputs stay identical
     to the unfused path."""
     layers = _chain_banks(45, dims=(8, 8, 64, 8, 5))    # N = (8, 64, 8, 5)

@@ -9,7 +9,7 @@ import pytest
 from repro.core import init_pegasus_linear
 from repro.core.amm import apply_gather
 from repro.core.fuzzy_tree import fit_tree, stack_trees
-from repro.kernels.fuzzy_lut.kernel import fuzzy_lut_pallas
+from repro.kernels.fuzzy_lut.kernel import fuzzy_lut_pallas, stack_layout
 from repro.kernels.fuzzy_lut.ops import fuzzy_lut_matmul, prepare_feat_onehot
 from repro.kernels.fuzzy_lut.ref import fuzzy_lut_matmul_ref, tree_descent_ref
 
@@ -131,6 +131,12 @@ def _stacked_problem(rng, l, t, kmax, v, depth, nmax, ks, n_out):
     return map(jnp.asarray, (x, feat_oh, thr, lut, bias))
 
 
+def _stack_args(x, feat_oh, thr, lut, bias):
+    """Flat batch + the stacked kernel's plan-build layout."""
+    return (x.reshape(x.shape[0], -1),
+            *stack_layout(feat_oh, thr, lut, bias))
+
+
 @pytest.mark.parametrize("strategy", ["lookup", "mxu"])
 def test_stack_kernel_matches_chained_single_bank(strategy):
     """The stacked-layer kernel ≡ chaining the single-bank kernel per layer
@@ -142,8 +148,8 @@ def test_stack_kernel_matches_chained_single_bank(strategy):
     x, feat_oh, thr, lut, bias = _stacked_problem(
         rng, len(ks), t, max(ks), v, depth, 8, ks, n_out)
     got = fuzzy_lut_stack_pallas(
-        x, feat_oh, thr, lut, bias, depth=depth, ks=ks, n_out=n_out,
-        strategy=strategy)
+        *_stack_args(x, feat_oh, thr, lut, bias), depth=depth, ks=ks,
+        n_out=n_out, strategy=strategy)
 
     h = x
     for layer, k in enumerate(ks):
@@ -165,12 +171,12 @@ def test_stack_kernel_tiles_batch():
 
     rng = np.random.default_rng(9)
     ks, v, depth, n_out = (4, 4), 2, 3, 8
-    x, feat_oh, thr, lut, bias = _stacked_problem(
-        rng, len(ks), 64, max(ks), v, depth, 8, ks, n_out)
-    one = fuzzy_lut_stack_pallas(x, feat_oh, thr, lut, bias, depth=depth,
-                                 ks=ks, n_out=n_out, block_t=64)
-    many = fuzzy_lut_stack_pallas(x, feat_oh, thr, lut, bias, depth=depth,
-                                  ks=ks, n_out=n_out, block_t=16)
+    args = _stack_args(*_stacked_problem(
+        rng, len(ks), 64, max(ks), v, depth, 8, ks, n_out))
+    one = fuzzy_lut_stack_pallas(*args, depth=depth, ks=ks, n_out=n_out,
+                                 block_t=64)
+    many = fuzzy_lut_stack_pallas(*args, depth=depth, ks=ks, n_out=n_out,
+                                  block_t=16)
     np.testing.assert_allclose(np.asarray(one), np.asarray(many),
                                rtol=1e-5, atol=1e-5)
 
@@ -192,14 +198,25 @@ def test_block_divisibility_raises_value_error():
                          block_t=12, block_n=3, block_k=4)
 
     ks, v, depth, n_out = (4, 4), 4, 2, 8
-    sx, sf, st_, sl, sb = _stacked_problem(
-        rng, 2, 12, 4, v, depth, 16, ks, n_out)
+    args = _stack_args(*_stacked_problem(
+        rng, 2, 12, 4, v, depth, 16, ks, n_out))
     with pytest.raises(ValueError, match=r"T=12 % block 8"):
-        fuzzy_lut_stack_pallas(sx, sf, st_, sl, sb, depth=depth, ks=ks,
+        fuzzy_lut_stack_pallas(*args, depth=depth, ks=ks,
                                n_out=n_out, block_t=8)
     with pytest.raises(ValueError, match="ks has 3 entries"):
-        fuzzy_lut_stack_pallas(sx, sf, st_, sl, sb, depth=depth,
+        fuzzy_lut_stack_pallas(*args, depth=depth,
                                ks=(4, 4, 4), n_out=n_out, block_t=12)
+
+
+def test_resolve_strategy_refuses_compiled_lookup():
+    """``lookup`` gathers exist only under the interpreter: a compiled
+    request is refused up front, not deep inside Mosaic."""
+    from repro.kernels.fuzzy_lut.kernel import resolve_strategy
+
+    with pytest.raises(ValueError, match="lookup"):
+        resolve_strategy("lookup", interpret=False)
+    assert resolve_strategy("lookup", interpret=True) == "lookup"
+    assert resolve_strategy("auto", interpret=False) == "mxu"
 
 
 if HAVE_HYPOTHESIS:

@@ -52,6 +52,7 @@ def test_q8_kernel_matches_ref(t, k, v, depth, n, blocks):
 def test_q8_stack_kernel_matches_chained_q8(strategy):
     """Stacked q8 kernel (dequant folded into the in-VMEM table) ≡ chaining
     the single-bank q8 kernel with the same int8 tables and scales."""
+    from repro.kernels.fuzzy_lut.kernel import stack_layout
     from repro.kernels.fuzzy_lut.quantized import fuzzy_lut_stack_q8_pallas
 
     rng = np.random.default_rng(11)
@@ -78,8 +79,11 @@ def test_q8_stack_kernel_matches_chained_q8(strategy):
     x = jnp.asarray(rng.normal(size=(t, ks[0], v)).astype(np.float32))
     args = tuple(map(jnp.asarray, (feat_oh, thr, lut_q8, scales, bias)))
 
-    got = fuzzy_lut_stack_q8_pallas(x, *args, depth=depth, ks=ks,
-                                    n_out=n_out, strategy=strategy)
+    sel, thr_col, lut_t, bias_col, scale_row = stack_layout(
+        args[0], args[1], args[2], args[4], args[3])
+    got = fuzzy_lut_stack_q8_pallas(
+        x.reshape(t, -1), sel, thr_col, lut_t, scale_row, bias_col,
+        depth=depth, ks=ks, n_out=n_out, strategy=strategy)
     h = x
     for layer, k in enumerate(ks):
         n = n_out if layer == l - 1 else ks[layer + 1] * v

@@ -1,0 +1,9 @@
+"""Share of the traced slice in which no operation ran on the device, in %:
+1 - busy / window, busy the union of the device's operation intervals,
+averaged over the cell's chips."""
+
+
+def read(ctx):
+    if ctx.trace is None or not ctx.trace["window_s"]:
+        return None
+    return 100.0 * (1.0 - ctx.trace["busy_s"] / ctx.trace["window_s"])

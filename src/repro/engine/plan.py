@@ -56,6 +56,7 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.analysis.sanitizer import make_lock
@@ -738,15 +739,17 @@ class ExecutionPlan:
         b = int(np.shape(inputs[0])[0])
         bucket = bucket_batch(b, self.buckets)
         target = self._batch_sharding if device is None else device
-        padded = tuple(self._owned_padded(x, bucket, target) for x in inputs)
-        STATS.jit_calls += 1
-        with self._ctr.lock:
-            self.jit_calls += 1
-            rows = self._ctr.rows.setdefault((be, bucket), [0, 0])
-            rows[0] += b
-            rows[1] += bucket
-        y = self._jit(self._state_for(device), padded, backend=be)
-        return y if bucket == b else y[:b]
+        with TraceAnnotation("plan.call"):
+            padded = tuple(self._owned_padded(x, bucket, target)
+                           for x in inputs)
+            STATS.jit_calls += 1
+            with self._ctr.lock:
+                self.jit_calls += 1
+                rows = self._ctr.rows.setdefault((be, bucket), [0, 0])
+                rows[0] += b
+                rows[1] += bucket
+            y = self._jit(self._state_for(device), padded, backend=be)
+            return y if bucket == b else y[:b]
 
     def lower(self, *inputs, backend: str | None = None):
         """Ahead-of-time lowering of the jitted forward for inputs already

@@ -51,6 +51,8 @@ import time
 from collections import deque
 from concurrent.futures import Future, InvalidStateError
 
+from jax.profiler import TraceAnnotation
+
 from repro.analysis.sanitizer import (ThreadAffinity, ThreadAffinityError,
                                       make_lock)
 
@@ -188,13 +190,15 @@ class DeviceStreamPool:
                 f"thread {threading.current_thread().name} is not a "
                 "DeviceStreamPool worker")
 
-    def submit(self, fn, flows: int) -> Future:
+    def submit(self, fn, flows: int, *, round_id: int = 0) -> Future:
         """Place ``fn(device)`` on the least-loaded healthy stream; returns
         a Future.
 
         ``flows`` is the work size used for the load signal — pass the
         chunk's flow count (NOT the padded bucket size: the caller knows
         the real rows, and padding is uniform per bucket anyway).
+        ``round_id`` is the caller's drain round, carried as the ``round``
+        argument of the worker's ``devices.run`` trace span.
 
         With zero healthy streams (every worker dead or quarantined) the
         chunk runs INLINE on this thread — degraded but never deadlocked —
@@ -210,7 +214,7 @@ class DeviceStreamPool:
             s = self._place(flows, orphans)
             if s is not None:
                 s.pending_flows += flows
-                s.q.append((fn, flows, fut))
+                s.q.append((fn, flows, fut, round_id))
                 self._work.notify_all()
             else:
                 self._inline_dispatches += 1
@@ -244,7 +248,7 @@ class DeviceStreamPool:
                     if not s.q and self._closed:
                         return
                     item = s.q.popleft()
-                fn, flows, fut = item
+                fn, flows, fut, round_id = item
                 # chaos hook OUTSIDE the per-dispatch except, deliberately:
                 # an injected raise kills this worker exactly like any
                 # unexpected error would, exercising the supervision path
@@ -258,7 +262,9 @@ class DeviceStreamPool:
                     continue
                 t0 = time.perf_counter()
                 try:
-                    out = fn(s.device)
+                    with TraceAnnotation("devices.run", round=round_id,
+                                         flows=flows):
+                        out = fn(s.device)
                 except BaseException as exc:  # noqa: BLE001 — future carries it
                     with self._lock:
                         s.pending_flows -= flows

@@ -48,6 +48,7 @@ import concurrent.futures
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis.sanitizer import ThreadAffinity, make_lock
 from repro.configs.registry import ArchConfig, get_config, smoke_config
@@ -380,6 +381,31 @@ def _coalesce(requests, *, host: bool = False) -> tuple[list, list[int], int]:
     return cat, sizes, sum(sizes)
 
 
+def _on_stream(pool, plan, chunk: tuple, backend, device) -> np.ndarray:
+    """One chunk on a device-stream worker: the plan call, the wait for its
+    result and the copy to the host, so the drain thread blocks on none of
+    them. ``assert_worker`` pins "ALL plan calls run on pool workers" under
+    the sanitizer (a no-op unless enabled)."""
+    pool.assert_worker()
+    y = plan(*chunk, backend=backend, device=device)
+    _wait_if_traced(y)
+    with TraceAnnotation("serve.to_host"):
+        return np.asarray(y)
+
+
+def _wait_if_traced(y: jax.Array) -> None:
+    """While a profiler records, wait for ``y`` in a ``serve.wait`` span of
+    its own, so the trace parts the wait on the device from the copy to the
+    host that follows. The copy is enqueued first, as ``np.asarray`` alone
+    would, so the device starts it the moment the result is ready. With no
+    profiler the copy waits by itself: the separate wait is a second
+    blocking call, and cost MLP-B about 3 % of its flows/s on a TPU v5e."""
+    if TraceAnnotation.is_enabled():
+        y.copy_to_host_async()
+        with TraceAnnotation("serve.wait"):
+            jax.block_until_ready(y)
+
+
 def _split(out: jax.Array, sizes: list[int]) -> list[np.ndarray]:
     """Cut a coalesced output back into per-request numpy arrays."""
     if len(sizes) == 1:
@@ -495,6 +521,9 @@ class MultiModelServer:
         # like schedule_log
         self._retry_streak: dict[str, int] = {}
         self._retry_not_before: dict[str, float] = {}
+        # drain-round number carried by the serve.round and devices.run
+        # trace spans; dispatch thread only, like the retry pacing above
+        self._round = 0
         # fault-injection hook — None until install_chaos(); the hot path
         # pays one is-None check per dispatched slice (repro.launch.chaos)
         self._chaos = None
@@ -671,16 +700,17 @@ class MultiModelServer:
                  timeout: float | None,
                  deadline_ms: float | None = None,
                  priority: str = "normal") -> int:
-        self._tracked(name)
-        # stage host inputs on the device now, off the dispatch thread —
-        # except in front of a device pool, whose streams each take their
-        # chunks straight from the host
-        xp = np if self._pool is not None else jnp
-        inputs = tuple(x if isinstance(x, jax.Array) else xp.asarray(x)
-                       for x in inputs)
-        return self._sched.submit(name, inputs, int(np.shape(inputs[0])[0]),
-                                  future=future, timeout=timeout,
-                                  deadline_ms=deadline_ms, priority=priority)
+        with TraceAnnotation("serve.submit"):
+            self._tracked(name)
+            # stage host inputs on the device now, off the dispatch thread
+            # — except in front of a device pool, whose streams each take
+            # their chunks straight from the host
+            xp = np if self._pool is not None else jnp
+            inputs = tuple(x if isinstance(x, jax.Array) else xp.asarray(x)
+                           for x in inputs)
+            return self._sched.submit(
+                name, inputs, int(np.shape(inputs[0])[0]), future=future,
+                timeout=timeout, deadline_ms=deadline_ms, priority=priority)
 
     def submit(self, request, *legacy_inputs, timeout: float | None = None,
                deadline_ms: float | None = None) -> int:
@@ -761,81 +791,83 @@ class MultiModelServer:
         ``"error"`` key."""
         from repro.engine import bucket_chunks
 
-        # sanitizer checkpoint: once the async loop binds the dispatch
-        # affinity, ANY other thread reaching this dispatch edge is the
-        # "two concurrent dispatchers" bug (unbound → free, sync path)
-        self._dispatch_affinity.assert_here()
-        t0 = time.perf_counter()
-        # queue-wait ends HERE, not at pull time: a round's groups dispatch
-        # sequentially, so later (lower-priority) groups keep waiting while
-        # earlier ones run — the stamp must capture that ordering effect
-        for r in reqs:
-            r.t_dispatch = t0
-        # "managed" = no explicit caller backend override: only managed
-        # groups ride the fallback ladder and feed the model's breaker (an
-        # explicit per-drain backend is the caller experimenting, not the
-        # serving path the breaker guards)
-        g: dict = {"name": name, "reqs": reqs, "t0": t0, "degraded": False,
-                   "probe": False, "managed": backend is None}
-        try:
-            br = self._breaker(name) if g["managed"] else None
-            if br is not None and br.state != CLOSED:
-                # fallback ladder: the preferred-path breaker is tripped.
-                # A granted cooldown probe retries the preferred backend
-                # (success auto-reinstates); otherwise this slice serves
-                # DEGRADED on the gather fallback plan — same model, same
-                # tables, least-machinery backend.
-                if br.allow():
-                    g["probe"] = True
+        with TraceAnnotation("serve.begin",
+                             flows=sum(r.size for r in reqs)):
+            # sanitizer checkpoint: once the async loop binds the dispatch
+            # affinity, ANY other thread reaching this dispatch edge is the
+            # "two concurrent dispatchers" bug (unbound → free, sync path)
+            self._dispatch_affinity.assert_here()
+            t0 = time.perf_counter()
+            # queue-wait ends HERE, not at pull time: a round's groups
+            # dispatch sequentially, so later (lower-priority) groups keep
+            # waiting while earlier ones run — the stamp must capture that
+            # ordering effect
+            for r in reqs:
+                r.t_dispatch = t0
+            # "managed" = no explicit caller backend override: only managed
+            # groups ride the fallback ladder and feed the model's breaker
+            # (an explicit per-drain backend is the caller experimenting,
+            # not the serving path the breaker guards)
+            g: dict = {"name": name, "reqs": reqs, "t0": t0,
+                       "degraded": False, "probe": False,
+                       "managed": backend is None}
+            try:
+                br = self._breaker(name) if g["managed"] else None
+                if br is not None and br.state != CLOSED:
+                    # fallback ladder: the preferred-path breaker is
+                    # tripped. A granted cooldown probe retries the
+                    # preferred backend (success auto-reinstates);
+                    # otherwise this slice serves DEGRADED on the gather
+                    # fallback plan — same model, same tables,
+                    # least-machinery backend.
+                    if br.allow():
+                        g["probe"] = True
+                    else:
+                        g["degraded"] = True
+                if self._chaos is not None:
+                    self._chaos.fire(
+                        "plan_call", model=name,
+                        backend=(FALLBACK_BACKEND if g["degraded"] else
+                                 backend or self.registry.backend_of(name)))
+                if g["degraded"]:
+                    plan = self.registry.get_with_backend(
+                        name, FALLBACK_BACKEND)
                 else:
-                    g["degraded"] = True
-            if self._chaos is not None:
-                self._chaos.fire(
-                    "plan_call", model=name,
-                    backend=(FALLBACK_BACKEND if g["degraded"] else
-                             backend or self.registry.backend_of(name)))
-            if g["degraded"]:
-                plan = self.registry.get_with_backend(name, FALLBACK_BACKEND)
-            else:
-                plan = self.registry.get(name)
-            if g["degraded"] or g["probe"]:
-                with self._ctr_lock:
-                    h = self._health_ctrs.get(name)
-                    if h is not None:
-                        key = ("fallback_batches" if g["degraded"]
-                               else "probe_batches")
-                        h[key] += 1
-            cat, sizes, total = _coalesce([r.inputs for r in reqs],
-                                          host=self._pool is not None)
-            chunks = bucket_chunks(total, plan.buckets, self.max_batch)
-            outs, start = [], 0
-            for size in chunks:
-                sl = (cat if start == 0 and size == total
-                      else [c[start : start + size] for c in cat])
-                if self._pool is None:
-                    outs.append(plan(*sl, backend=backend))
-                else:
-                    # the chunk runs on whichever stream has the least
-                    # pending work; np conversion happens ON that worker so
-                    # the block is off this thread too. assert_worker is
-                    # the sanitizer's thread-affinity pin for "ALL plan
-                    # calls run on pool workers" (no-op unless enabled).
-                    outs.append(self._pool.submit(
-                        lambda d, plan=plan, sl=tuple(sl): (
-                            self._pool.assert_worker(),
-                            np.asarray(plan(*sl, backend=backend,
-                                            device=d)))[1],
-                        size))
-                self.schedule_log.append(name)
-                with self._ctr_lock:
-                    self.batches_dispatched += 1
-                start += size
-        except Exception as e:
-            g["error"] = e
+                    plan = self.registry.get(name)
+                if g["degraded"] or g["probe"]:
+                    with self._ctr_lock:
+                        h = self._health_ctrs.get(name)
+                        if h is not None:
+                            key = ("fallback_batches" if g["degraded"]
+                                   else "probe_batches")
+                            h[key] += 1
+                cat, sizes, total = _coalesce([r.inputs for r in reqs],
+                                              host=self._pool is not None)
+                chunks = bucket_chunks(total, plan.buckets, self.max_batch)
+                outs, start = [], 0
+                for size in chunks:
+                    sl = (cat if start == 0 and size == total
+                          else [c[start : start + size] for c in cat])
+                    if self._pool is None:
+                        outs.append(plan(*sl, backend=backend))
+                    else:
+                        # the chunk runs on whichever stream has the least
+                        # pending work; np conversion happens ON that
+                        # worker so the block is off this thread too
+                        outs.append(self._pool.submit(
+                            lambda d, plan=plan, sl=tuple(sl): _on_stream(
+                                self._pool, plan, sl, backend, d),
+                            size, round_id=self._round))
+                    self.schedule_log.append(name)
+                    with self._ctr_lock:
+                        self.batches_dispatched += 1
+                    start += size
+            except Exception as e:
+                g["error"] = e
+                return g
+            g.update(outs=outs, sizes=sizes, total=total,
+                     batches=len(chunks), t_begun=time.perf_counter())
             return g
-        g.update(outs=outs, sizes=sizes, total=total, batches=len(chunks),
-                 t_begun=time.perf_counter())
-        return g
 
     def _finish_group(self, g: dict):
         """Phase 2: block on the group's device results, split per request,
@@ -854,7 +886,8 @@ class MultiModelServer:
                     # pool mode: outs are futures of per-chunk NP arrays on
                     # DIFFERENT devices — concatenate on the host (jnp
                     # would refuse to mix committed devices)
-                    arrs = [f.result() for f in g["outs"]]
+                    with TraceAnnotation("serve.wait"):
+                        arrs = [f.result() for f in g["outs"]]
                     out = (np.concatenate(arrs, axis=0)
                            if len(arrs) > 1 else arrs[0])
                     split = ([out] if len(g["sizes"]) == 1 else
@@ -863,7 +896,9 @@ class MultiModelServer:
                 else:
                     out = (jnp.concatenate(g["outs"], axis=0)
                            if len(g["outs"]) > 1 else g["outs"][0])
-                    split = _split(out, g["sizes"])  # np conversion: sync
+                    _wait_if_traced(out)
+                    with TraceAnnotation("serve.to_host"):
+                        split = _split(out, g["sizes"])
             except Exception as e:
                 err = e
         # the breaker sees only the PREFERRED path: a degraded (fallback)
@@ -981,19 +1016,21 @@ class MultiModelServer:
         failed: set = set()
         quantum = self._quantum()
         while True:
-            groups = self._sched.pull_round(quantum, exclude=failed)
-            if not groups:
-                break
-            # two phases: dispatch EVERY group, then block on each — the
-            # device works across models while the host splits/converts
-            begun = [self._begin_group(name, reqs, backend)
-                     for name, reqs in groups]
-            for g in begun:
-                outs = self._finish_group(g)
-                if outs is None:
-                    failed.add(g["name"])  # skip for the rest of this drain
-                else:
-                    results.setdefault(g["name"], []).extend(outs)
+            self._round += 1
+            with TraceAnnotation("serve.round", round=self._round):
+                groups = self._sched.pull_round(quantum, exclude=failed)
+                if not groups:
+                    break
+                # two phases: dispatch EVERY group, then block on each — the
+                # device works across models while the host splits/converts
+                begun = [self._begin_group(name, reqs, backend)
+                         for name, reqs in groups]
+                for g in begun:
+                    outs = self._finish_group(g)
+                    if outs is None:
+                        failed.add(g["name"])  # skip for the rest of drain
+                    else:
+                        results.setdefault(g["name"], []).extend(outs)
         self.last_shed = {name: len(reqs)
                           for name, reqs in self._sched.take_shed().items()}
         if self.last_drain_errors and not results:
@@ -1430,41 +1467,45 @@ class AsyncMultiModelServer(MultiModelServer):
     def _serve_loop_body(self) -> None:
         while not self._stop_flag.is_set():
             try:
-                # re-read per round: server.quantum is documented as a live
-                # tunable, so the loop must not cache it at thread start.
-                # Models inside their retry backoff window are excluded —
-                # their requeued-at-front work waits out the pause while
-                # every other model keeps draining.
-                now = time.perf_counter()
-                backoff = frozenset(
-                    n for n, t in self._retry_not_before.items() if t > now)
-                groups = self._sched.pull_round(self._quantum(),
-                                                exclude=backoff)
+                self._round += 1
+                with TraceAnnotation("serve.round", round=self._round):
+                    # re-read per round: server.quantum is documented as a
+                    # live tunable, so the loop must not cache it at thread
+                    # start. Models inside their retry backoff window are
+                    # excluded — their requeued-at-front work waits out the
+                    # pause while every other model keeps draining.
+                    now = time.perf_counter()
+                    backoff = frozenset(
+                        n for n, t in self._retry_not_before.items()
+                        if t > now)
+                    groups = self._sched.pull_round(self._quantum(),
+                                                    exclude=backoff)
+                    # two-phase like drain(): enqueue every model's chunks
+                    # on the device before blocking on any result. Async
+                    # failures land on the futures, never requeue — a
+                    # poisoned request must not wedge the loop forever.
+                    begun = [self._begin_group(name, reqs, None)
+                             for name, reqs in groups]
+                    for g in begun:
+                        try:
+                            self._finish_group(g)
+                        except Exception as e:   # unexpected: _finish_group
+                            # already routes dispatch errors onto futures,
+                            # so anything escaping it would otherwise
+                            # strand this group's futures AND skip every
+                            # later group's
+                            self.loop_errors.append(e)
+                            for r in g["reqs"]:
+                                _resolve_future(r.future, error=e)
                 if not groups:
-                    if backoff:
-                        # wait_for_work returns immediately while the
-                        # backed-off work sits queued; pace the retry loop
-                        # instead of spinning on it
-                        time.sleep(0.002)
-                    else:
-                        self._sched.wait_for_work(self._idle_wait)
-                    continue
-                # two-phase like drain(): enqueue every model's chunks on
-                # the device before blocking on any result. Async failures
-                # land on the futures, never requeue — a poisoned request
-                # must not wedge the loop forever.
-                begun = [self._begin_group(name, reqs, None)
-                         for name, reqs in groups]
-                for g in begun:
-                    try:
-                        self._finish_group(g)
-                    except Exception as e:       # unexpected: _finish_group
-                        # already routes dispatch errors onto futures, so
-                        # anything escaping it would otherwise strand this
-                        # group's futures AND skip every later group's
-                        self.loop_errors.append(e)
-                        for r in g["reqs"]:
-                            _resolve_future(r.future, error=e)
+                    with TraceAnnotation("sched.wait"):
+                        if backoff:
+                            # wait_for_work returns immediately while the
+                            # backed-off work sits queued; pace the retry
+                            # loop instead of spinning on it
+                            time.sleep(0.002)
+                        else:
+                            self._sched.wait_for_work(self._idle_wait)
             except Exception as e:               # pragma: no cover - safety
                 self.loop_errors.append(e)
                 time.sleep(self._idle_wait)

@@ -62,7 +62,7 @@ def main(quick: bool = False):
     for name, fn in [
         ("gather", jax.jit(lambda xb: apply_gather(layer, xb))),
         ("onehot", jax.jit(lambda xb: apply_onehot(layer, xb))),
-        ("kernel(interp)", lambda xb: fuzzy_lut_matmul(layer, xb, block_t=256, block_n=128, block_k=32)),
+        ("kernel(interp)", lambda xb: fuzzy_lut_matmul(layer, xb)),
     ]:
         fn(x).block_until_ready()
         t0 = time.perf_counter()

@@ -21,7 +21,7 @@ paper's compiler enforces on the P4 target before deployment:
   build-time version of the kernel docstring's working-set math.
 * **PGA104** — tile alignment: ladder buckets that silently dispatch hidden
   pad rows (the kernel pads the batch up to its tile multiple, uncounted by
-  ``pad_waste``), and single-bank mxu LUT tiles missing 128-lane alignment.
+  ``pad_waste``).
 * **PGA105** — fusion-rejection explanations: why each adjacent chained
   bank pair is NOT inside one :class:`FusedBankStack` (v/C mismatch,
   chaining break, ``nmax_cap`` split, ``fuse=False``, or a family builder
@@ -148,14 +148,12 @@ class AuditReport:
 
 
 def _true_tables(bank) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """(f32 LUT, q8 LUT, scales) sliced back to the bank's TRUE (K, C, N)
-    — the block-padded rows are zeros/inf filler with no numeric content."""
-    layer = bank.layer
-    k, n = layer.num_groups, layer.out_features
-    lut = np.asarray(bank.lut_p, np.float64)[:k, :, :n]
-    q8 = np.asarray(bank.lut_q8_p, np.int64)[:k, :, :n]
-    scales = np.asarray(bank.scales, np.float64)[:k]
-    return lut, q8, scales
+    """(f32 LUT, q8 LUT, scales) read back out of the bank's kernel layout
+    at its TRUE (K, C, N) — the padded rows are zeros/inf filler with no
+    numeric content."""
+    lut, q8, scales = bank.tables()
+    return (lut.astype(np.float64), q8.astype(np.int64),
+            scales.astype(np.float64))
 
 
 def accumulation_grid(scales: np.ndarray) -> float:
@@ -250,35 +248,35 @@ def _check_fidelity(plan, cfg: AuditConfig):
                 {"rel_err": worst, "group": g, "tol": cfg.q8_rel_tol})
 
 
-def _single_vmem_bytes(bank) -> int:
-    """Worst-case per-program VMEM working set of the single-bank kernel
-    (see the kernel.py module docstring): x block + one-hot/threshold
-    blocks + LUT block + out block, f32, plus the q8 path's int8 table
-    copy that is dequantized in-register."""
-    l = bank.layer
-    v, c = l.group_size, l.num_centroids
-    i = c - 1
-    bt, bk, bn = bank.block_t, bank.block_k, bank.block_n
-    floats = bt * bk * v + bk * i * v + bk * i + bk * c * bn + bt * bn
-    return 4 * floats + bk * c * bn          # + int8 LUT block (q8 path)
+def _vmem_bytes(layers: int, nodes: int, w: int, bt: int) -> int:
+    """VMEM working set of one pallas_call step (kernel.py module
+    docstring): the batch tile's ``[W, Tt]`` input/output blocks, per layer
+    the ``[nodes, W]`` select, ``[nodes, 1]`` thresholds, ``[W, nodes]`` f32
+    LUT (plus its int8 copy and scale row on the q8 path) and ``[W, 1]``
+    bias, and the ``[nodes, Tt]`` descent intermediates of one layer."""
+    floats = (2 * w * bt                                  # x^T + out^T tiles
+              + layers * nodes * (w + 1)                  # select + thr
+              + layers * w * nodes                        # f32 LUT
+              + layers * nodes + layers * w               # scales + bias
+              + 4 * nodes * bt)                           # vals/bits/one-hots
+    return 4 * floats + layers * w * nodes                # + int8 LUT
+
+
+def _single_vmem_bytes(bank, max_bucket: int) -> int:
+    """Worst-case VMEM working set of the single-bank kernel: one
+    (batch tile, group tile) step, its blocks cut to ``Kt·C`` nodes."""
+    bt, kt = bank.tiles
+    return _vmem_bytes(1, kt * bank.layer.num_centroids, bank.lut.shape[0],
+                       min(bt, max(max_bucket, 1)))
 
 
 def _stack_vmem_bytes(stack, max_bucket: int) -> int:
-    """Worst-case VMEM working set of one stacked pallas_call (kernel.py
-    module docstring): the batch tile's ``[W, Tt]`` input/output blocks,
-    EVERY per-layer operand riding whole (that is the point of the fusion —
-    the activation never leaves VMEM), and the ``[K·C, Tt]`` descent
-    intermediates of one layer."""
-    ll = len(stack.banks)
+    """Worst-case VMEM working set of one stacked pallas_call: EVERY
+    per-layer operand rides whole (that is the point of the fusion — the
+    activation never leaves VMEM)."""
     kc = max(stack.ks) * stack.banks[0].layer.num_centroids
-    w = stack.width
-    bt = min(stack.block_t, max(max_bucket, 1))
-    floats = (2 * w * bt                                  # x^T + out^T tiles
-              + ll * kc * (w + 1)                         # select + thr
-              + ll * w * kc                               # f32 LUT stack
-              + ll * kc + ll * w                          # scales + bias
-              + 4 * kc * bt)                              # vals/bits/one-hots
-    return 4 * floats + ll * w * kc                       # + int8 stack
+    return _vmem_bytes(len(stack.banks), kc, stack.width,
+                       min(stack.block_t, max(max_bucket, 1)))
 
 
 def _iter_steps(plan):
@@ -302,9 +300,10 @@ def _check_vmem(plan, cfg: AuditConfig):
             shape = (f"L={len(step.banks)}, Kmax={max(step.ks)}, "
                      f"W={step.width}, bt={step.block_t}")
         else:
-            need = _single_vmem_bytes(step)
-            shape = (f"bt={step.block_t}, bk={step.block_k}, "
-                     f"bn={step.block_n}")
+            need = _single_vmem_bytes(step, max_bucket)
+            bt, kt = step.tiles
+            shape = (f"K={step.layer.num_groups}, Kt={kt}, "
+                     f"W={step.lut.shape[0]}, bt={bt}")
         metrics = {"bytes": need, "budget": budget, "shape": shape}
         if need > budget:
             yield AuditFinding(
@@ -328,12 +327,14 @@ def _check_alignment(plan, cfg: AuditConfig):
     tiles = {}                          # (bt_limit, kind) -> example site
     for site, step in _iter_steps(plan):
         if hasattr(step, "ks"):
-            tiles.setdefault((step.block_t, "stack", False), site)
+            tiles.setdefault((step.block_t, "stack"), site)
         else:
-            tiles.setdefault((step.block_t, "bank", True), site)
-    for (limit, kind, floor8), site in sorted(tiles.items()):
+            tiles.setdefault((step.tiles[0], "bank"), site)
+    for (limit, kind), site in sorted(tiles.items()):
         for bucket in plan.buckets:
-            bt = min(limit, max(8, bucket) if floor8 else bucket)
+            # a batch under the tile fills its 128-lane vregs either way:
+            # only rows beyond the bucket's own lane tiles are hidden work
+            bt = min(limit, bucket)
             hidden = (-bucket) % bt
             if hidden:
                 yield AuditFinding(
@@ -342,18 +343,6 @@ def _check_alignment(plan, cfg: AuditConfig):
                     f"tile {bt} ({site}): the kernel path silently pads "
                     f"{hidden} extra rows per call, uncounted by pad_waste",
                     {"bucket": bucket, "tile": bt, "hidden_rows": hidden})
-    # MXU lane alignment: the single-bank mxu matmul wants the LUT column
-    # tile 128-lane aligned; misalignment wastes systolic-array lanes. (A
-    # fused stack puts the batch tile on lanes, which the ladder aligns.)
-    for site, step in _iter_steps(plan):
-        if step.strategy != "mxu" or hasattr(step, "ks"):
-            continue
-        if step.block_n % R.MXU_LANES:
-            yield AuditFinding(
-                "PGA104", "warning", site,
-                f"mxu strategy with block_n={step.block_n} not "
-                f"{R.MXU_LANES}-lane aligned — MXU tiles run partially empty",
-                {"width": step.block_n, "lanes": R.MXU_LANES})
 
 
 def _unfused_reasons(a, b) -> list[str]:

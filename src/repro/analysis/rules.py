@@ -193,8 +193,7 @@ PGA_RULES = {
               "(operand blocks + stacked tables) exceeds the per-target "
               "VMEM budget (error) or is within the margin of it (warning)",
     "PGA104": "kernel-tile alignment: a ladder bucket dispatches hidden pad "
-              "rows (bucket not divisible by the batch tile), or an "
-              "mxu-strategy LUT width misses 128-lane alignment",
+              "rows (bucket not divisible by the batch tile)",
     "PGA105": "fusion rejection: an adjacent chained bank pair did not fuse "
               "(v/C mismatch, chaining break, nmax_cap split, fuse=False, "
               "or a family builder without the fusion pass)",
@@ -218,9 +217,6 @@ PGA102_REL_TOL = 1.0 / 100.0
 # common TPU figure; override per target via AuditConfig.
 PGA103_VMEM_BUDGET = 16 * 2**20
 PGA103_MARGIN = 2.0
-
-# PGA104: MXU lane width the mxu strategy wants LUT columns aligned to.
-MXU_LANES = 128
 
 # -- comment grammar --------------------------------------------------------
 

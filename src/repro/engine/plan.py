@@ -7,10 +7,10 @@ and FENIX's offload pipeline both treat that state as *precompiled*; this
 module does the same for the TPU realization:
 
   * :class:`CompiledBank` — one ``PegasusLinear`` plus every tensor the fused
-    Pallas kernel needs, built exactly once (`feat_onehot`, +inf-padded
-    thresholds, block-padded LUT, int8 LUT + per-group scales). Registered as
-    a jax pytree so a whole plan's banks flow through ``jax.jit`` as traced
-    state rather than baked-in constants.
+    Pallas kernel needs, built exactly once in the kernel's transposed layout
+    (one-hot feature select, +inf-padded thresholds, LUT, int8 LUT +
+    per-group scales). Registered as a jax pytree so a whole plan's banks
+    flow through ``jax.jit`` as traced state rather than baked-in constants.
   * :class:`FusedBankStack` / :func:`fuse_banks` — Cross-bank Primitive
     Fusion: a maximal run of shape-compatible consecutive banks (same group
     width and centroid count, each bank's output feeding the next's input)
@@ -64,6 +64,7 @@ from repro.analysis.sanitizer import make_lock
 from repro.core.amm import PegasusLinear, apply_gather, apply_onehot
 from repro.core.fuzzy_tree import hard_index
 from repro.kernels.fuzzy_lut.kernel import (
+    bank_tiles,
     default_interpret,
     fuzzy_lut_pallas,
     fuzzy_lut_stack_pallas,
@@ -71,7 +72,11 @@ from repro.kernels.fuzzy_lut.kernel import (
     stack_block_t,
     stack_layout,
 )
-from repro.kernels.fuzzy_lut.ops import prepare_feat_onehot, quantized_lut_cached
+from repro.kernels.fuzzy_lut.ops import (
+    bank_operands,
+    prepare_feat_onehot,
+    quantized_lut_cached,
+)
 from repro.kernels.fuzzy_lut.quantized import (
     fuzzy_lut_q8_pallas,
     fuzzy_lut_stack_q8_pallas,
@@ -214,11 +219,15 @@ def _pad_to(x: jax.Array, axis: int, mult: int, value: float = 0.0) -> jax.Array
 class CompiledBank:
     """One PegasusLinear with its kernel layout precomputed and frozen.
 
-    All layout work (one-hot of split features, +inf threshold padding,
-    block padding of the LUT along K and N, int8 quantization + scales)
-    happens in ``__init__``; ``apply`` only pads the activations.
+    All layout work (one-hot of split features, the single-bank kernel's
+    transposed ``[W, K·C]`` layout with +inf thresholds and zero LUT rows
+    on padded groups, int8 quantization + scales) happens in ``__init__``
+    (:func:`~repro.kernels.fuzzy_lut.ops.bank_operands`); ``apply`` only
+    hands the activations to the kernel, whose launcher transposes and
+    pads them. The batch and group tiles follow from the bank's shape
+    (:attr:`tiles`).
 
-    Pytree protocol: the tensors are leaves, the block geometry is static
+    Pytree protocol: the tensors are leaves, the static kernel config is
     aux data — so banks can ride through ``jax.jit`` as arguments (shared
     across every compiled bucket) instead of being re-embedded as XLA
     constants in each executable.
@@ -234,9 +243,6 @@ class CompiledBank:
         self,
         layer: PegasusLinear,
         *,
-        block_t: int = 256,
-        block_n: int = 256,
-        block_k: int = 128,
         interpret: bool | None = None,
         strategy: str = "auto",
     ):
@@ -244,43 +250,38 @@ class CompiledBank:
         # the same model); the replica below is what the bank retains.
         lut_q8, scales = quantized_lut_cached(layer)
         self.layer = dataclasses.replace(layer)
-        self.block_t = block_t
         self.interpret = default_interpret() if interpret is None else interpret
         self.strategy = resolve_strategy(strategy, self.interpret)
-
-        k, v, n = layer.num_groups, layer.group_size, layer.out_features
         self.depth = int(np.log2(layer.num_centroids) + 0.5)
 
         # -- layout prep: done ONCE here, never on the call path -----------
-        bk = min(block_k, k)
-        kp = k + (-k) % bk
-        feat_oh = prepare_feat_onehot(layer.trees.features, v)
-        thr = layer.trees.thresholds
-        lut = layer.lut
-        if kp != k:
-            feat_oh = _pad_to(feat_oh, 0, bk)
-            thr = jnp.pad(thr, ((0, kp - k), (0, 0)), constant_values=jnp.inf)
-            lut = _pad_to(lut, 0, bk)
-            lut_q8 = _pad_to(lut_q8, 0, bk)
-            scales = jnp.pad(scales, (0, kp - k))
-        bn = min(block_n, n)
-        self.feat_oh = feat_oh
-        self.thr = thr
-        self.lut_p = _pad_to(lut, 2, bn)
-        self.lut_q8_p = _pad_to(lut_q8, 2, bn)
-        self.scales = scales
-        self.kp = kp
-        self.block_n = min(block_n, self.lut_p.shape[2])
-        self.block_k = min(block_k, kp)
+        self.sel, self.thr, self.lut, self.bias = bank_operands(
+            layer, layer.lut)
+        self.lut_q8, self.scales = bank_operands(layer, lut_q8, scales)[2:4]
         STATS.layout_builds += 1
+
+    @property
+    def tiles(self) -> tuple[int, int]:
+        """(batch tile, group tile) the single-bank kernel runs with."""
+        c = self.layer.num_centroids
+        return bank_tiles(self.sel.shape[0] // c, c)
+
+    def tables(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """The f32 LUT and int8 LUT ``[K, C, N]`` and the per-group scales
+        ``[K]``, read back out of the kernel layout at true size (host
+        numpy; no requantization)."""
+        p = self.layer
+        k, c, n = p.num_groups, p.num_centroids, p.out_features
+        lut, lut_q8 = (np.asarray(t)[:n, :k * c].T.reshape(k, c, n)
+                       for t in (self.lut, self.lut_q8))
+        return lut, lut_q8, np.asarray(self.scales)[0, :k * c:c]
 
     # -- pytree protocol ----------------------------------------------------
 
     def tree_flatten(self):
-        children = (self.layer, self.feat_oh, self.thr,
-                    self.lut_p, self.lut_q8_p, self.scales)
-        aux = (self.block_t, self.block_n, self.block_k,
-               self.depth, self.kp, self.interpret, self.strategy)
+        children = (self.layer, self.sel, self.thr, self.lut, self.lut_q8,
+                    self.scales, self.bias)
+        aux = (self.depth, self.interpret, self.strategy)
         return children, aux
 
     @classmethod
@@ -288,10 +289,9 @@ class CompiledBank:
         # bypass __init__: no layout work, no STATS increment — this path
         # runs on every jit flatten/unflatten round-trip
         obj = object.__new__(cls)
-        (obj.layer, obj.feat_oh, obj.thr,
-         obj.lut_p, obj.lut_q8_p, obj.scales) = children
-        (obj.block_t, obj.block_n, obj.block_k,
-         obj.depth, obj.kp, obj.interpret, obj.strategy) = aux
+        (obj.layer, obj.sel, obj.thr, obj.lut, obj.lut_q8, obj.scales,
+         obj.bias) = children
+        obj.depth, obj.interpret, obj.strategy = aux
         return obj
 
     # -- backend dispatch ---------------------------------------------------
@@ -302,38 +302,20 @@ class CompiledBank:
             return apply_gather(self.layer, x)
         if backend == "onehot":
             return apply_onehot(self.layer, x)
+        if backend not in ("kernel", "kernel_q8"):
+            raise ValueError(
+                f"unknown backend {backend!r}; expected one of {BACKENDS}")
+        n = self.layer.out_features
+        xf = x.reshape(-1, x.shape[-1])
+        kw = dict(depth=self.depth, n_out=n, interpret=self.interpret,
+                  strategy=self.strategy)
         if backend == "kernel":
-            return self._apply_kernel(x, self.lut_p, None)
-        if backend == "kernel_q8":
-            return self._apply_kernel(x, self.lut_q8_p, self.scales)
-        raise ValueError(f"unknown backend {backend!r}; expected one of {BACKENDS}")
-
-    def _apply_kernel(self, x, lut, scales) -> jax.Array:
-        p = self.layer
-        k, v, n = p.num_groups, p.group_size, p.out_features
-        lead = x.shape[:-1]
-        xg = x.reshape(-1, k, v).astype(jnp.float32)
-        t = xg.shape[0]
-        bt = min(self.block_t, max(8, t))
-        xg = _pad_to(_pad_to(xg, 0, bt), 1, self.block_k)
-        if scales is None:
-            y = fuzzy_lut_pallas(
-                xg, self.feat_oh, self.thr, lut,
-                depth=self.depth, block_t=bt, block_n=self.block_n,
-                block_k=self.block_k, interpret=self.interpret,
-                strategy=self.strategy,
-            )
+            y = fuzzy_lut_pallas(xf, self.sel, self.thr, self.lut, self.bias,
+                                 **kw)
         else:
-            y = fuzzy_lut_q8_pallas(
-                xg, self.feat_oh, self.thr, lut, scales,
-                depth=self.depth, block_t=bt, block_n=self.block_n,
-                block_k=self.block_k, interpret=self.interpret,
-                strategy=self.strategy,
-            )
-        y = y[:t, :n]
-        if p.bias is not None:
-            y = y + p.bias
-        return y.reshape(*lead, n)
+            y = fuzzy_lut_q8_pallas(xf, self.sel, self.thr, self.lut_q8,
+                                    self.scales, self.bias, **kw)
+        return y.reshape(*x.shape[:-1], n)
 
 
 # ---------------------------------------------------------------------------
@@ -386,17 +368,19 @@ class FusedBankStack:
         scales = jnp.zeros((len(layers), kmax), jnp.float32)
         bias = jnp.zeros((len(layers), nmax), jnp.float32)
         for l, bank in enumerate(banks):
-            k, n = bank.layer.num_groups, bank.layer.out_features
-            # slice the bank's block-padded operands back to true size, then
-            # re-pad to the GROUP geometry — no new quantization, no new
-            # one-hots: strictly a restack of what CompiledBank already built
-            feat_oh = feat_oh.at[l, :k].set(bank.feat_oh[:k])
-            thr = thr.at[l, :k].set(bank.thr[:k])
-            lut = lut.at[l, :k, :, :n].set(bank.lut_p[:k, :, :n])
-            lut_q8 = lut_q8.at[l, :k, :, :n].set(bank.lut_q8_p[:k, :, :n])
-            scales = scales.at[l, :k].set(bank.scales[:k])
-            if bank.layer.bias is not None:
-                bias = bias.at[l, :n].set(bank.layer.bias)
+            p = bank.layer
+            k, n = p.num_groups, p.out_features
+            # true-size tables padded to the GROUP geometry — no new
+            # quantization: the int8 rows are read back out of the bank
+            _, q8, sc = bank.tables()
+            feat_oh = feat_oh.at[l, :k].set(
+                prepare_feat_onehot(p.trees.features, self.v))
+            thr = thr.at[l, :k].set(p.trees.thresholds)
+            lut = lut.at[l, :k, :, :n].set(p.lut)
+            lut_q8 = lut_q8.at[l, :k, :, :n].set(q8)
+            scales = scales.at[l, :k].set(sc)
+            if p.bias is not None:
+                bias = bias.at[l, :n].set(p.bias)
         # the kernel's transposed layout, built once here (stack_layout)
         self.sel, self.thr, self.lut, self.bias, self.scales = stack_layout(
             feat_oh, thr, lut, bias, scales)
@@ -919,8 +903,8 @@ class ExecutionPlan:
         """Total LUT bytes held by the plan (fp + q8 layouts)."""
         total = 0
         for b in self.banks:
-            total += b.lut_p.size * b.lut_p.dtype.itemsize
-            total += b.lut_q8_p.size * b.lut_q8_p.dtype.itemsize
+            total += b.lut.size * b.lut.dtype.itemsize
+            total += b.lut_q8.size * b.lut_q8.dtype.itemsize
         return total
 
 
@@ -1042,9 +1026,6 @@ def build_plan(
     model: Any,
     *,
     backend: str = "onehot",
-    block_t: int = 256,
-    block_n: int = 256,
-    block_k: int = 128,
     interpret: bool | None = None,
     strategy: str = "auto",
     bucket_sizes: Sequence[int] | None = None,
@@ -1070,11 +1051,6 @@ def build_plan(
             ``"gather"`` | ``"onehot"`` | ``"kernel"`` | ``"kernel_q8"``
             (:data:`BACKENDS`); overridable per call via
             ``plan(x, backend=...)``. Unknown names raise ``ValueError``.
-        block_t / block_n / block_k: Pallas kernel tile sizes (rows of the
-            batch / LUT output columns / tree-descent lanes per program).
-            Only the kernel backends read them; defaults suit the bank
-            shapes the nets produce. Mis-sized tiles fail inside
-            ``pallas_call`` at first trace, not at build.
         interpret: ``True`` forces Pallas interpret mode (slow, runs
             anywhere), ``False`` requires a compiled Pallas backend,
             ``None`` (default) resolves via :func:`default_interpret` —
@@ -1125,9 +1101,9 @@ def build_plan(
     a plan you hold: rebuild it, or go through :func:`plan_for`, whose memo
     detects bank swaps and non-bank reassignment and recompiles.
     """
-    kw = dict(block_t=block_t, block_n=block_n, block_k=block_k,
-              interpret=default_interpret() if interpret is None else interpret,
-              strategy=strategy)
+    if interpret is None:
+        interpret = default_interpret()
+    kw = dict(interpret=interpret, strategy=strategy)
     if isinstance(model, PegasusLinear):
         plan = _sequential_plan([model], backend, kw, bucket_sizes, fuse,
                                 fuse_nmax_cap, devices)

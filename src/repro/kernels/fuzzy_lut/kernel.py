@@ -3,11 +3,11 @@
 TPU-native realization of the paper's table lookup:
 
   * The clustering-tree descent is *gather-free*: feature selection becomes a
-    tiny one-hot matmul (the one-hot is precomputed offline), node selection
-    a one-hot reduction — every step is VPU compare/select or MXU matmul, so
+    one-hot matmul (the one-hot is precomputed offline), node selection a
+    one-hot reduction — every step is VPU compare/select or MXU matmul, so
     the whole "fuzzy match" is branchless and systolic-friendly.
   * The Map (leaf→row lookup) + SumReduce (Σ over groups) pair is ONE MXU
-    matmul: ``onehot(leaf): [Tt, Kt·C] @ LUT-block: [Kt·C, Nt]`` — the same
+    matmul: ``LUT^T: [W, K·C] @ onehot(leaf): [K·C, Tt]`` — the same
     primitive-fusion insight as the paper's, re-expressed for a systolic
     array instead of a MAT stage.
 
@@ -15,38 +15,56 @@ Every matmul that carries a value (an activation or a LUT entry) runs at
 ``Precision.HIGHEST``: a one-hot select must return the activation exactly,
 or a tree decision next to its threshold flips.
 
-Single-bank tiling (BlockSpec, all VMEM):
-  grid = (T/Tt, N/Nt, K/Kt);   K innermost → output block accumulates.
-    x        [T, K, v]   → block (Tt, Kt, v)      index (i, k, 0)
-    feat_oh  [K, I, v]   → block (Kt, I, v)       index (k, 0, 0)   I = 2^d - 1
-    thr      [K, I]      → block (Kt, I)          index (k, 0)
-    lut      [K, C, N]   → block (Kt, C, Nt)      index (k, 0, j)
-    out      [T, N]      → block (Tt, Nt)         index (i, j)
+Both kernels work in one *transposed, lane-dense* layout that
+:func:`stack_layout` builds once at plan build: flows on lanes, features and
+tree nodes on sublanes. The per-group tree walk then splits only the sublane
+axis (``[K·C, Tt] → [K, C, Tt]``), which Mosaic lays out tile for tile, and
+nothing is relaid out inside a kernel. Per bank:
+
+    x^T      [W, T]        flows on lanes
+    sel      [K·C, W]      one-hot: node (k, n) reads feature k·v+f
+    thr      [K·C, 1]      +inf on the padding node n = C-1
+    lut^T    [W, K·C]      f32, or int8 with scales [1, K·C]
+    bias     [W, 1]
+    out^T    [W, T]
+
+``W`` (the activation width, a multiple of 8) covers the bank's input and
+output. Padded groups hold +inf thresholds and zero LUT rows, so they
+descend to leaf 0 and contribute nothing.
+
+Single-bank tiling (:func:`fuzzy_lut_pallas`, operands from
+:func:`bank_layout`):
+  grid = (T/Tt, K/Kt);   groups innermost ("arbitrary") → out accumulates
+    x^T      [W, T]      → block (W, Tt)        index (0, i)
+    sel      [K·C, W]    → block (Kt·C, W)      index (g, 0)
+    thr      [K·C, 1]    → block (Kt·C, 1)      index (g, 0)
+    lut^T    [W, K·C]    → block (W, Kt·C)      index (0, g)
+    bias     [W, 1]      → whole, added after the last group tile
+    out^T    [W, T]      → block (W, Tt)        index (0, i)
+
+``(Tt, Kt)`` follow from the bank's shape (:func:`bank_tiles`): the stacked
+kernel's batch tile, and as many groups per step as keep each ``[Kt·C, Tt]``
+f32 descent intermediate within :data:`STACK_TILE_BYTES`. The launcher pads
+the batch to a tile multiple itself (a batch under the tile to whole
+128-lane tiles).
 
 Stacked-layer variant (:func:`fuzzy_lut_stack_pallas` — Cross-bank Primitive
 Fusion): a compatible run of L banks executes as ONE kernel invocation. The
 grid tiles ONLY the batch; every per-layer operand rides whole (stacked along
-a leading L axis), so the inter-bank activation never leaves VMEM. The stack
-works in a *transposed, lane-dense* layout that :func:`stack_layout` builds
-once at plan build: flows on lanes, features and tree nodes on sublanes. In
-that layout the between-bank re-partition is free — the next bank's
-block-diagonal one-hot select reads its groups straight out of the previous
-bank's output rows — and the per-group tree walk splits only the sublane
-axis (``[K·C, Tt] → [K, C, Tt]``), which Mosaic lays out tile for tile:
+a leading L axis), so the inter-bank activation never leaves VMEM, and the
+between-bank re-partition is free — the next bank's block-diagonal one-hot
+select reads its groups straight out of the previous bank's output rows:
 
   grid = (T/Tt,)
     x^T      [W, T]          → block (W, Tt)           index (0, i)
-    sel      [L, K·C, W]     → whole   one-hot: node (k, n) reads feature k·v+f
-    thr      [L, K·C, 1]     → whole   +inf on the padding node n = C-1
+    sel      [L, K·C, W]     → whole
+    thr      [L, K·C, 1]     → whole
     lut^T    [L, W, K·C]     → whole   f32, or int8 with scales [L, 1, K·C]
     bias     [L, W, 1]       → whole
     out^T    [W, T]          → block (W, Tt)           index (0, i)
 
-``W`` (the activation width, a multiple of 8) covers the stack's widest
-input and output. Padded groups hold +inf thresholds and zero LUT rows, so
-they descend to leaf 0 and contribute nothing. The batch tile is derived
-from the bank shape (:func:`stack_block_t`) so the ``[K·C, Tt]`` descent
-intermediates stay ≈1 MiB each.
+There ``W`` covers the stack's widest input and output, and the batch tile
+(:func:`stack_block_t`) keeps the ``[K·C, Tt]`` intermediates ≈1 MiB each.
 
 Both entry points raise ``ValueError`` (never ``assert``, which dies
 silently under ``python -O``) when a dimension is not block-divisible, so
@@ -62,12 +80,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["default_interpret", "fuzzy_lut_kernel", "fuzzy_lut_pallas",
-           "fuzzy_lut_stack_pallas", "resolve_strategy", "stack_block_t",
-           "stack_layout"]
+__all__ = ["bank_layout", "bank_tiles", "default_interpret",
+           "fuzzy_lut_kernel", "fuzzy_lut_pallas", "fuzzy_lut_stack_pallas",
+           "resolve_strategy", "stack_block_t", "stack_layout"]
 
-# Byte budget of ONE [K·C, Tt] f32 descent intermediate of the stacked
-# kernel (values, bits, one-hots); the batch tile follows from it.
+# Byte budget of ONE [K·C, Tt] f32 descent intermediate (values, bits,
+# one-hots) of either kernel; the batch and group tiles follow from it.
 STACK_TILE_BYTES = 1 << 20
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -87,113 +105,6 @@ def default_interpret() -> bool:
 
 def _tpu_compiler_params(dimension_semantics: tuple[str, ...]):
     return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
-
-
-def _tree_leaf(x, feat_oh, thr, *, depth: int, strategy: str):
-    """Shared descent: [Tt, Kt, v] activations → [Tt, Kt] leaf indices.
-
-    Both strategies compute the SAME bits (identical fp compare); they differ
-    in how much of the tree they touch:
-      ``mxu``    — score EVERY internal node up front (one-hot einsum for the
-                   feature select, one-hot reduction per level): branchless
-                   and gather-free — what the systolic/VPU path wants.
-      ``lookup`` — walk only the ``depth`` visited nodes, one flat-index
-                   gather per level (features recovered from the one-hot via
-                   argmax, [Kt, I] — tiny): no [Tt, Kt, I] intermediates at
-                   all, which is what the interpreter/CPU wants (the dense
-                   form materializes I/d ≈ 10× more values than the walk
-                   reads).
-    """
-    tt, kt = x.shape[0], x.shape[1]
-    n_internal = thr.shape[-1]
-    node = jnp.zeros((tt, kt), dtype=jnp.int32)
-    if strategy == "lookup":
-        # sparse walk: gather (feature, threshold) of the CURRENT node only
-        feat_flat = jnp.argmax(feat_oh, axis=-1).astype(jnp.int32).reshape(-1)
-        thr_flat = thr.reshape(-1)
-        base = (jnp.arange(kt, dtype=jnp.int32) * n_internal)[None]  # [1, Kt]
-        for _ in range(depth):
-            idx = node + base                                 # [Tt, Kt]
-            f_sel = jnp.take(feat_flat, idx)
-            t_sel = jnp.take(thr_flat, idx)
-            val = jnp.take_along_axis(x, f_sel[:, :, None], axis=2)[..., 0]
-            node = 2 * node + 1 + (val > t_sel).astype(jnp.int32)
-        return node - n_internal                  # [Tt, Kt] in [0, C)
-
-    # dense scoring: vals[t,k,n] = x[t,k,feat[k,n]] as an einsum against the
-    # precomputed one-hot (gather-free), then one-hot-select per level.
-    vals = jax.lax.dot_general(
-        x,
-        feat_oh,
-        # contract v; batch over k
-        dimension_numbers=(((2,), (2,)), ((1,), (0,))),
-        precision=_HIGHEST,
-        preferred_element_type=jnp.float32,
-    )                                             # [Kt, Tt, I]
-    vals = vals.transpose(1, 0, 2)                # [Tt, Kt, I]
-    bits = (vals > thr[None]).astype(jnp.int32)   # decision at every node
-    iota_nodes = jax.lax.broadcasted_iota(jnp.int32, (tt, kt, n_internal), 2)
-    for _ in range(depth):
-        node_oh = (iota_nodes == node[:, :, None]).astype(jnp.int32)
-        bit = jnp.sum(bits * node_oh, axis=-1)    # [Tt, Kt]
-        node = 2 * node + 1 + bit
-    return node - n_internal                      # [Tt, Kt] in [0, C)
-
-
-def _lut_contrib(leaf, lut, *, strategy: str, scale=None):
-    """Map + SumReduce over one tile: [Tt, Kt] leaves × [Kt, C, Nt] LUT →
-    [Tt, Nt] contributions. ``scale`` ([Kt] per-group dequant factors, q8
-    path) folds in exactly — it is constant within a group, and both
-    realizations sum over (group, centroid).
-
-      ``mxu``    — onehot(leaf) [Tt, Kt·C] @ lut [Kt·C, Nt]: one systolic
-                   matmul, gather-free.
-      ``lookup`` — flat-index gather-sum (rows picked from the [Kt·C, Nt]
-                   table view): O(T·K·N) instead of the matmul's O(T·K·C·N);
-                   the interpreter/CPU-fast form.
-    """
-    tt, kt = leaf.shape
-    c = lut.shape[1]
-    if strategy == "lookup":
-        base = (jnp.arange(kt, dtype=jnp.int32) * c)[None]    # [1, Kt]
-        rows = jnp.take(lut.reshape(kt * c, -1), leaf + base,
-                        axis=0)                   # [Tt, Kt, Nt]
-        if scale is not None:
-            rows = rows * scale[None, :, None]
-        return rows.sum(axis=1)                   # [Tt, Nt]
-    iota_c = jax.lax.broadcasted_iota(jnp.int32, (tt, kt, c), 2)
-    onehot = (iota_c == leaf[:, :, None]).astype(jnp.float32)
-    if scale is not None:
-        onehot = onehot * scale[None, :, None]
-    return jax.lax.dot_general(
-        onehot.reshape(tt, kt * c),
-        lut.reshape(kt * c, -1),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        precision=_HIGHEST,
-        preferred_element_type=jnp.float32,
-    )                                             # [Tt, Nt]
-
-
-def fuzzy_lut_kernel(
-    x_ref, feat_oh_ref, thr_ref, lut_ref, out_ref, *, depth: int,
-    strategy: str = "mxu",
-):
-    """One (Tt, Nt, Kt) tile: descend trees, accumulate LUT rows into out."""
-    x = x_ref[...].astype(jnp.float32)            # [Tt, Kt, v]
-    feat_oh = feat_oh_ref[...].astype(jnp.float32)  # [Kt, I, v]
-    thr = thr_ref[...].astype(jnp.float32)        # [Kt, I]
-
-    leaf = _tree_leaf(x, feat_oh, thr, depth=depth, strategy=strategy)
-    lut = lut_ref[...].astype(jnp.float32)        # [Kt, C, Nt]
-    contrib = _lut_contrib(leaf, lut, strategy=strategy)
-
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        out_ref[...] = contrib
-
-    @pl.when(pl.program_id(2) != 0)
-    def _accum():
-        out_ref[...] += contrib
 
 
 def _check_divisible(where: str, **dims: tuple[int, int]) -> None:
@@ -231,59 +142,8 @@ def resolve_strategy(strategy: str, interpret: bool) -> str:
     return strategy
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("depth", "block_t", "block_n", "block_k", "interpret",
-                     "strategy"),
-)
-def fuzzy_lut_pallas(
-    x: jax.Array,          # [T, K, v]
-    feat_oh: jax.Array,    # [K, I, v] one-hot of split features (offline)
-    thresholds: jax.Array, # [K, I]
-    lut: jax.Array,        # [K, C, N]
-    *,
-    depth: int,
-    block_t: int = 256,
-    block_n: int = 256,
-    block_k: int = 128,
-    interpret: bool | None = None,
-    strategy: str = "auto",
-) -> jax.Array:
-    """Pallas-tiled fused Pegasus matmul. Returns [T, N] f32 (no bias).
-
-    Fully traceable inside an outer ``jax.jit`` (the engine jits whole-plan
-    forwards through here); ``interpret`` and ``strategy`` are static args,
-    ``None``/``"auto"`` resolve via :func:`default_interpret` /
-    :func:`resolve_strategy`.
-    """
-    if interpret is None:
-        interpret = default_interpret()
-    strategy = resolve_strategy(strategy, interpret)
-    t, k, v = x.shape
-    _, c, n = lut.shape
-    bt, bn, bk = min(block_t, t), min(block_n, n), min(block_k, k)
-    _check_divisible("fuzzy_lut_pallas", T=(t, bt), N=(n, bn), K=(k, bk))
-    n_internal = c - 1
-
-    grid = (t // bt, n // bn, k // bk)
-    return pl.pallas_call(
-        functools.partial(fuzzy_lut_kernel, depth=depth, strategy=strategy),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bt, bk, v), lambda i, j, kk: (i, kk, 0)),
-            pl.BlockSpec((bk, n_internal, v), lambda i, j, kk: (kk, 0, 0)),
-            pl.BlockSpec((bk, n_internal), lambda i, j, kk: (kk, 0)),
-            pl.BlockSpec((bk, c, bn), lambda i, j, kk: (kk, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((bt, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((t, n), jnp.float32),
-        compiler_params=_tpu_compiler_params(("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(x, feat_oh, thresholds, lut)
-
-
 # ---------------------------------------------------------------------------
-# Stacked-layer variant: L compatible banks in ONE kernel invocation
+# Layout and tiles, built once at plan build
 # ---------------------------------------------------------------------------
 
 
@@ -329,6 +189,56 @@ def stack_block_t(kc: int) -> int:
     while bt > 128 and kc * bt * 4 > STACK_TILE_BYTES:
         bt //= 2
     return bt
+
+
+def bank_tiles(k: int, c: int) -> tuple[int, int]:
+    """``(Tt, Kt)`` of the single-bank kernel for ``K`` groups of ``C`` tree
+    nodes: the stacked kernel's batch tile (:func:`stack_block_t`), and the
+    whole bank per step unless ``[K·C, Tt]`` outgrows
+    :data:`STACK_TILE_BYTES` even at the 128-row floor — then the groups
+    split evenly over the fewest steps that fit it, each step spanning a
+    whole number of 128-lane tiles of nodes, as the ``[W, Kt·C]`` LUT block
+    needs."""
+    bt = stack_block_t(k * c)
+    fit = max(1, STACK_TILE_BYTES // (4 * c * bt))
+    if fit >= k:
+        return bt, k
+    steps = -(-k // fit)
+    lanes = max(1, 128 // c)
+    return bt, -(-k // (steps * lanes)) * lanes
+
+
+@jax.jit
+def bank_layout(feat_oh, thr, lut, bias, scales=None) -> tuple:
+    """Plan-build layout of ONE bank for the single-bank kernels:
+    :func:`stack_layout` with L = 1, the groups padded (+inf thresholds,
+    zero LUT rows and scales) to a multiple of the group tile
+    (:func:`bank_tiles`). One jitted computation per bank shape, so a
+    plan build compiles a handful of them rather than every layout op.
+
+    Takes ``feat_oh [K, I, v]``, ``thr [K, I]``, ``lut [K, C, N]`` (f32 or
+    int8), ``bias [N]`` and, for int8, ``scales [K]``. Returns the kernel's
+    operands in its argument order: ``(sel [K·C, W], thr [K·C, 1], lut_t
+    [W, K·C], bias [W, 1])``, the scales as ``[1, K·C]`` before ``bias``
+    when given.
+    """
+    k, c = lut.shape[:2]
+    pad = (-k) % bank_tiles(k, c)[1]
+
+    def groups(a, value=0):
+        return jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1),
+                       constant_values=value)[None]
+
+    ops = (groups(feat_oh), groups(thr, jnp.inf), groups(lut), bias[None])
+    if scales is not None:
+        ops += (groups(scales),)
+    out = tuple(a[0] for a in stack_layout(*ops))
+    return out[:3] + out[4:] + out[3:4]
+
+
+# ---------------------------------------------------------------------------
+# Kernel bodies
+# ---------------------------------------------------------------------------
 
 
 def _stack_layer_mxu(h, sel, thr, tab, *, depth: int):
@@ -395,9 +305,68 @@ def fuzzy_lut_stack_kernel(x_ref, sel_ref, thr_ref, lut_ref, bias_ref,
         depth=depth, strategy=strategy)
 
 
+def _bank_step(x_ref, sel_ref, thr_ref, lut_ref, scale_ref, bias_ref,
+               out_ref, *, depth: int, strategy: str):
+    """One (batch tile, group tile) step of a single bank: the group tiles'
+    contributions accumulate in the resident ``[W, Tt]`` output block, and
+    the bias joins after the last of them. ``scale_ref`` dequantizes the
+    int8 table as in :func:`_stack_layers`; ``None`` on the fp path."""
+    layer = _stack_layer_mxu if strategy == "mxu" else _stack_layer_lookup
+    tab = lut_ref[...].astype(jnp.float32)                 # [W, Kt·C]
+    if scale_ref is not None:
+        tab = tab * scale_ref[...]                         # [1, Kt·C] row
+    contrib = layer(x_ref[...], sel_ref[...], thr_ref[...], tab, depth=depth)
+    g = pl.program_id(1)
+
+    @pl.when(g == 0)
+    def _init():
+        out_ref[...] = contrib
+
+    @pl.when(g != 0)
+    def _accum():
+        out_ref[...] += contrib
+
+    @pl.when(g == pl.num_programs(1) - 1)
+    def _bias():
+        out_ref[...] += bias_ref[...]
+
+
+def fuzzy_lut_kernel(x_ref, sel_ref, thr_ref, lut_ref, bias_ref, out_ref, *,
+                     depth: int, strategy: str):
+    """One (batch tile, group tile) step of the fp32 single-bank kernel."""
+    _bank_step(x_ref, sel_ref, thr_ref, lut_ref, None, bias_ref, out_ref,
+               depth=depth, strategy=strategy)
+
+
+# ---------------------------------------------------------------------------
+# Launchers
+# ---------------------------------------------------------------------------
+
+
 def _whole_block(ndim: int):
     """Index map of an operand that rides whole in every grid step."""
-    return lambda i: (0,) * ndim
+    return lambda *_: (0,) * ndim
+
+
+def _transposed_call(kernel, x, operands, in_specs, *, w: int, bt: int,
+                     grid: tuple[int, ...], semantics: tuple[str, ...],
+                     n_out: int, interpret: bool) -> jax.Array:
+    """Transpose the ``[T, d]`` batch into ``[W, T]`` rows, padded to a
+    whole number of ``bt``-lane tiles; run ``kernel`` over ``grid`` with
+    the batch tile as grid axis 0; return ``[T, n_out]``."""
+    t, d0 = x.shape
+    tp = -(-t // bt) * bt
+    xt = jnp.pad(x.astype(jnp.float32).T, ((0, w - d0), (0, tp - t)))
+    out = pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=[pl.BlockSpec((w, bt), lambda i, *_: (0, i))] + in_specs,
+        out_specs=pl.BlockSpec((w, bt), lambda i, *_: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((w, tp), jnp.float32),
+        compiler_params=_tpu_compiler_params(semantics),
+        interpret=interpret,
+    )(xt, *operands)
+    return out[:n_out, :t].T
 
 
 def stack_call(kernel, x, operands, *, depth: int, ks: tuple[int, ...],
@@ -420,19 +389,80 @@ def stack_call(kernel, x, operands, *, depth: int, ks: tuple[int, ...],
             f"holds {kc} tree nodes over {w} activation rows per layer")
     bt = min(stack_block_t(kc) if block_t is None else block_t, t)
     _check_divisible(where, T=(t, bt))
-
     whole = [pl.BlockSpec(op.shape, _whole_block(op.ndim)) for op in operands]
-    xt = jnp.pad(x.astype(jnp.float32).T, ((0, w - d0), (0, 0)))
-    out = pl.pallas_call(
-        functools.partial(kernel, depth=depth, strategy=strategy),
-        grid=(t // bt,),
-        in_specs=[pl.BlockSpec((w, bt), lambda i: (0, i))] + whole,
-        out_specs=pl.BlockSpec((w, bt), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((w, t), jnp.float32),
-        compiler_params=_tpu_compiler_params(("parallel",)),
-        interpret=interpret,
-    )(xt, *operands)
-    return out[:n_out].T
+    return _transposed_call(
+        functools.partial(kernel, depth=depth, strategy=strategy), x,
+        operands, whole, w=w, bt=bt, grid=(t // bt,), semantics=("parallel",),
+        n_out=n_out, interpret=interpret)
+
+
+def bank_call(kernel, x, operands, *, depth: int, n_out: int,
+              interpret: bool | None, strategy: str, where: str) -> jax.Array:
+    """Shared launcher of the fp32 and int8 single-bank kernels. Operands
+    come from :func:`bank_layout`: ``(sel, thr, lut_t, bias)``, the int8
+    scales between ``lut_t`` and ``bias``. Transposes the ``[T, K·v]``
+    batch into the ``[W, T]`` layout, tiles it by :func:`bank_tiles`
+    (padding the batch to the tile, or to whole 128-lane tiles under it),
+    accumulates over group tiles, and returns ``[T, n_out]`` with the bias
+    applied."""
+    if interpret is None:
+        interpret = default_interpret()
+    strategy = resolve_strategy(strategy, interpret)
+    sel, thr, *tables, bias = operands
+    kc, w = sel.shape
+    c = 1 << depth
+    t, d0 = x.shape
+    if d0 > w or n_out > w or kc % c:
+        raise ValueError(
+            f"{where}: x carries {d0} features and n_out={n_out}, but the "
+            f"bank holds {kc} tree nodes of depth {depth} over {w} "
+            "activation rows")
+    bt, kt = bank_tiles(kc // c, c)
+    _check_divisible(where, K=(kc // c, kt))
+    nodes = kt * c
+    specs = ([pl.BlockSpec((nodes, op.shape[1]), lambda i, g: (g, 0))
+              for op in (sel, thr)]
+             + [pl.BlockSpec((op.shape[0], nodes), lambda i, g: (0, g))
+                for op in tables]
+             + [pl.BlockSpec(bias.shape, _whole_block(2))])
+    # a batch under the tile runs as whole 128-lane tiles, the width a vreg
+    # holds anyway: so a flow's sums never depend on how many flows share
+    # its call (a sharded plan's per-device slice included)
+    bt = min(bt, -(-t // 128) * 128)
+    return _transposed_call(
+        functools.partial(kernel, depth=depth, strategy=strategy), x,
+        operands, specs, w=w, bt=bt, grid=(-(-t // bt), kc // nodes),
+        semantics=("parallel", "arbitrary"), n_out=n_out,
+        interpret=interpret)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("depth", "n_out", "interpret", "strategy"))
+def fuzzy_lut_pallas(
+    x: jax.Array,          # [T, K·v]
+    sel: jax.Array,        # [K·C, W] one-hot feature select (bank_layout)
+    thr: jax.Array,        # [K·C, 1]
+    lut: jax.Array,        # [W, K·C] f32, transposed
+    bias: jax.Array,       # [W, 1] (zeros where the bank has no bias)
+    *,
+    depth: int,
+    n_out: int,            # true out_features (≤ W)
+    interpret: bool | None = None,
+    strategy: str = "auto",
+) -> jax.Array:
+    """One Pegasus bank, ONE ``pallas_call``: returns ``[T, n_out]`` f32,
+    bias applied.
+
+    Fully traceable inside an outer ``jax.jit`` (the engine jits whole-plan
+    forwards through here); ``interpret`` and ``strategy`` are static args,
+    ``None``/``"auto"`` resolve via :func:`default_interpret` /
+    :func:`resolve_strategy`. Tiles follow from the operand shapes
+    (:func:`bank_tiles`); groups not padded to the group tile raise
+    ``ValueError``.
+    """
+    return bank_call(fuzzy_lut_kernel, x, (sel, thr, lut, bias), depth=depth,
+                     n_out=n_out, interpret=interpret, strategy=strategy,
+                     where="fuzzy_lut_pallas")
 
 
 @functools.partial(

@@ -1,8 +1,8 @@
 """Jit'd public wrapper for the fused fuzzy-LUT matmul kernel.
 
-Handles layout prep (grouping, one-hot features, padding to block multiples)
-and exposes a `PegasusLinear`-level entry point used by the serving stack
-(`repro.core.amm.pegasus_linear_apply(..., path="kernel")`).
+Handles layout prep (one-hot features, the kernel's transposed layout,
+group padding) and exposes a `PegasusLinear`-level entry point used by the
+serving stack (`repro.core.amm.pegasus_linear_apply(..., path="kernel")`).
 """
 
 from __future__ import annotations
@@ -13,12 +13,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernel import fuzzy_lut_pallas
+from .kernel import bank_layout, fuzzy_lut_pallas
 
 __all__ = [
-    "fuzzy_lut_matmul", "fuzzy_lut_matmul_q8", "padded_layout",
-    "prepare_feat_onehot", "quantized_lut_cached", "LAYOUT_STATS",
-    "QUANT_STATS",
+    "bank_operands", "fuzzy_lut_matmul", "fuzzy_lut_matmul_q8",
+    "padded_layout", "prepare_feat_onehot", "quantized_lut_cached",
+    "LAYOUT_STATS", "QUANT_STATS",
 ]
 
 # int8-LUT memo: production deployments quantize offline exactly once; the
@@ -28,13 +28,10 @@ __all__ = [
 QUANT_STATS = {"quantize_calls": 0, "cache_hits": 0}
 _Q8_MEMO: dict[int, tuple] = {}
 
-# Static-operand layout memo: the one-hot feature tensor and the
-# block-divisibility padding of (lut, thresholds, feat_oh) depend only on the
-# layer and block geometry, yet the wrappers below used to rebuild them on
-# EVERY call — a pad/copy of the whole table bank per invocation when shapes
-# weren't block-divisible. One entry per (layer id, block_k, block_n, q8?),
-# weakref-evicted with the layer like the q8 memo. At call time the cached
-# layout is shape-CHECKED, never re-padded: only the batch may pad per call.
+# Static-operand layout memo: the kernel layout of (feat_oh, thresholds,
+# lut, bias) depends only on the layer, so it is built once per (layer id,
+# q8?), weakref-evicted with the layer like the q8 memo; only the batch is
+# transposed and padded per call (inside the kernel's launcher).
 LAYOUT_STATS = {"layout_builds": 0, "cache_hits": 0}
 _LAYOUT_MEMO: dict[tuple, tuple] = {}
 
@@ -60,28 +57,23 @@ def prepare_feat_onehot(features: jax.Array, group_size: int) -> jax.Array:
     return jax.nn.one_hot(features, group_size, dtype=jnp.float32)
 
 
-def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
-    size = x.shape[axis]
-    rem = (-size) % mult
-    if rem == 0:
-        return x
-    pad = [(0, 0)] * x.ndim
-    pad[axis] = (0, rem)
-    return jnp.pad(x, pad)
+def bank_operands(layer, lut, scales=None) -> tuple:
+    """:func:`repro.kernels.fuzzy_lut.kernel.bank_layout` of a
+    PegasusLinear's trees and bias with ``lut`` — its f32 table, or an int8
+    table with ``scales``."""
+    bias = (jnp.zeros(layer.out_features) if layer.bias is None
+            else layer.bias)
+    return bank_layout(
+        prepare_feat_onehot(layer.trees.features, layer.group_size),
+        layer.trees.thresholds, lut, bias, scales)
 
 
-def padded_layout(layer, *, block_k: int, block_n: int, quant: bool):
-    """Block-padded static operands for one PegasusLinear, memoized.
-
-    Returns ``(feat_oh, thr, lut, scales, kp)`` — every tensor padded so the
-    kernel's divisibility contract holds (K → ``kp`` with +inf thresholds and
-    zero LUT rows, N → a ``block_n`` multiple with zero columns). ``scales``
-    is None unless ``quant``. Built exactly once per (layer, geometry); the
-    call-path wrappers ASSERT the cached shapes instead of re-padding.
-    """
-    k, v, n = layer.num_groups, layer.group_size, layer.out_features
-    bk, bn = min(block_k, k), min(block_n, n)
-    key = (id(layer), bk, bn, quant)
+def padded_layout(layer, *, quant: bool) -> tuple:
+    """The single-bank kernel's operands for one PegasusLinear, memoized:
+    :func:`repro.kernels.fuzzy_lut.kernel.bank_layout` of the layer —
+    ``(sel, thr, lut_t, bias)``, or ``(sel, thr, lut_q8_t, scales, bias)``
+    when ``quant``. Built exactly once per (layer, quant)."""
+    key = (id(layer), quant)
     entry = _LAYOUT_MEMO.get(key)
     if entry is not None and entry[0]() is layer:
         LAYOUT_STATS["cache_hits"] += 1
@@ -90,76 +82,31 @@ def padded_layout(layer, *, block_k: int, block_n: int, quant: bool):
             # q8 memo's observable hit contract for callers that count it
             QUANT_STATS["cache_hits"] += 1
         return entry[1]
-    feat_oh = prepare_feat_onehot(layer.trees.features, v)
-    thr = layer.trees.thresholds
-    scales = None
-    if quant:
-        lut, scales = quantized_lut_cached(layer)
-    else:
-        lut = layer.lut
-    kp = k + (-k) % bk
-    if kp != k:
-        feat_oh = _pad_to(feat_oh, 0, bk)
-        thr = jnp.pad(thr, ((0, kp - k), (0, 0)), constant_values=jnp.inf)
-        lut = _pad_to(lut, 0, bk)
-        if scales is not None:
-            scales = jnp.pad(scales, (0, kp - k))
-    lut = _pad_to(lut, 2, bn)
-    layout = (feat_oh, thr, lut, scales, kp)
+    layout = (bank_operands(layer, *quantized_lut_cached(layer)) if quant
+              else bank_operands(layer, layer.lut))
     LAYOUT_STATS["layout_builds"] += 1
     ref = weakref.ref(layer, lambda _ref, key=key: _LAYOUT_MEMO.pop(key, None))
     _LAYOUT_MEMO[key] = (ref, layout)
     return layout
 
 
-def fuzzy_lut_matmul(
-    layer,  # PegasusLinear (kept duck-typed to avoid import cycle)
-    x: jax.Array,
-    *,
-    block_t: int = 256,
-    block_n: int = 256,
-    block_k: int = 128,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """Apply a PegasusLinear via the Pallas kernel. x: [..., D] → [..., N]."""
-    k, v = layer.num_groups, layer.group_size
+def _depth(layer) -> int:
+    return int(np.log2(layer.num_centroids) + 0.5)
+
+
+def fuzzy_lut_matmul(layer, x: jax.Array, *,
+                     interpret: bool | None = None) -> jax.Array:
+    """Apply a PegasusLinear via the Pallas kernel. x: [..., D] → [..., N].
+    ``layer`` is duck-typed to avoid an import cycle."""
     n = layer.out_features
-    lead = x.shape[:-1]
-    xg = x.reshape(-1, k, v).astype(jnp.float32)
-    t = xg.shape[0]
-
-    # static operands come block-padded from the one-time layout memo
-    # (+inf thresholds on padded/degenerate nodes force all-left: keep);
-    # the only per-call padding left is the batch itself.
-    feat_oh, thr, lut, _, kp = padded_layout(
-        layer, block_k=block_k, block_n=block_n, quant=False)
-    assert lut.shape[0] == kp and thr.shape[0] == kp, (
-        "cached layout shape drifted — rebuild the layout memo")
-
-    bt = min(block_t, max(8, t))
-    xg_p = _pad_to(_pad_to(xg, 0, bt), 1, min(block_k, k))
-
     y = fuzzy_lut_pallas(
-        xg_p,
-        feat_oh,
-        thr,
-        lut,
-        depth=int(np.log2(layer.num_centroids) + 0.5),
-        block_t=bt,
-        block_n=min(block_n, lut.shape[2]),
-        block_k=min(block_k, kp),
-        interpret=interpret,
-    )
-    y = y[:t, :n]
-    if layer.bias is not None:
-        y = y + layer.bias
-    return y.reshape(*lead, n)
+        x.reshape(-1, x.shape[-1]), *padded_layout(layer, quant=False),
+        depth=_depth(layer), n_out=n, interpret=interpret)
+    return y.reshape(*x.shape[:-1], n)
 
 
-def fuzzy_lut_matmul_q8(
-    layer, x: jax.Array, *, block_t: int = 256, block_n: int = 256,
-    block_k: int = 128, interpret: bool | None = None,
-) -> jax.Array:
+def fuzzy_lut_matmul_q8(layer, x: jax.Array, *,
+                        interpret: bool | None = None) -> jax.Array:
     """int8-LUT kernel path: quantize the bank once, run the q8 kernel.
 
     Production deployments quantize offline and keep only the int8 LUT in
@@ -169,27 +116,8 @@ def fuzzy_lut_matmul_q8(
     """
     from .quantized import fuzzy_lut_q8_pallas
 
-    k, v = layer.num_groups, layer.group_size
     n = layer.out_features
-    lead = x.shape[:-1]
-    xg = x.reshape(-1, k, v).astype(jnp.float32)
-    t = xg.shape[0]
-
-    feat_oh, thr, lut_q8, scales, kp = padded_layout(
-        layer, block_k=block_k, block_n=block_n, quant=True)
-    assert lut_q8.shape[0] == kp and scales.shape[0] == kp, (
-        "cached layout shape drifted — rebuild the layout memo")
-
-    bt = min(block_t, max(8, t))
-    xg_p = _pad_to(_pad_to(xg, 0, bt), 1, min(block_k, k))
-
     y = fuzzy_lut_q8_pallas(
-        xg_p, feat_oh, thr, lut_q8, scales,
-        depth=int(np.log2(layer.num_centroids) + 0.5),
-        block_t=bt, block_n=min(block_n, lut_q8.shape[2]),
-        block_k=min(block_k, kp), interpret=interpret,
-    )
-    y = y[:t, :n]
-    if layer.bias is not None:
-        y = y + layer.bias
-    return y.reshape(*lead, n)
+        x.reshape(-1, x.shape[-1]), *padded_layout(layer, quant=True),
+        depth=_depth(layer), n_out=n, interpret=interpret)
+    return y.reshape(*x.shape[:-1], n)

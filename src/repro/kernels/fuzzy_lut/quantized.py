@@ -1,18 +1,17 @@
 """int8-LUT variant of the fused fuzzy-LUT kernel (beyond the paper).
 
 LUT rows are stored int8 with one f32 scale per partition group; the scale
-is folded into the one-hot BEFORE the MXU matmul (exact — the matmul sums
-over (group, centroid) and the scale is constant within a group):
+is folded into the table in VMEM, before the MXU matmul (exact — the matmul
+sums over (group, centroid) and the scale is constant within a group):
 
-    y = Σ_k s_k · LUT8[k, idx_k]  ==  (onehot ⊙ s)[T, K·C] @ LUT8[K·C, N]
+    y^T = Σ_k s_k · LUT8[k, idx_k] == (LUT8^T ⊙ s)[N, K·C] @ onehot[K·C, T]
 
 Wire effect at decode: LUT bytes halve vs bf16; with v=16, C=16 the total
 weight-byte cost is 0.5·(C/v)=0.5× the dense bf16 weights — the decode
 memory-roofline lever.
 
-The stacked variant stores the scales already broadcast per centroid
-(``[L, 1, K·C]``, :func:`repro.kernels.fuzzy_lut.kernel.stack_layout`) and
-folds them into each layer's table in VMEM.
+Both kernels here take the scales already broadcast per centroid (``[1,
+K·C]`` per bank, :func:`repro.kernels.fuzzy_lut.kernel.stack_layout`).
 """
 
 from __future__ import annotations
@@ -21,18 +20,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from .kernel import (
-    _check_divisible,
-    _lut_contrib,
-    _stack_layers,
-    _tpu_compiler_params,
-    _tree_leaf,
-    default_interpret,
-    resolve_strategy,
-    stack_call,
-)
+from .kernel import _bank_step, _stack_layers, bank_call, stack_call
 
 __all__ = ["quantize_lut_int8", "fuzzy_lut_q8_pallas", "fuzzy_lut_q8_ref",
            "fuzzy_lut_stack_q8_pallas"]
@@ -58,58 +47,27 @@ def fuzzy_lut_q8_ref(x, features, thresholds, lut_q8, scales):
     return (gathered * scales[None, :, None]).sum(axis=1)
 
 
-def _q8_kernel(x_ref, feat_oh_ref, thr_ref, lut_ref, scale_ref, out_ref, *,
-               depth, strategy: str = "mxu"):
-    x = x_ref[...].astype(jnp.float32)
-    feat_oh = feat_oh_ref[...].astype(jnp.float32)
-    thr = thr_ref[...].astype(jnp.float32)
-
-    leaf = _tree_leaf(x, feat_oh, thr, depth=depth, strategy=strategy)
-    contrib = _lut_contrib(
-        leaf, lut_ref[...].astype(jnp.float32), strategy=strategy,
-        scale=scale_ref[...].astype(jnp.float32))
-
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        out_ref[...] = contrib
-
-    @pl.when(pl.program_id(2) != 0)
-    def _accum():
-        out_ref[...] += contrib
-
-
 @functools.partial(
-    jax.jit, static_argnames=("depth", "block_t", "block_n", "block_k",
-                              "interpret", "strategy"))
+    jax.jit, static_argnames=("depth", "n_out", "interpret", "strategy"))
 def fuzzy_lut_q8_pallas(
-    x, feat_oh, thresholds, lut_q8, scales, *,
-    depth: int, block_t: int = 256, block_n: int = 256, block_k: int = 128,
-    interpret: bool | None = None, strategy: str = "auto",
+    x,            # [T, K·v]
+    sel,          # [K·C, W] one-hot feature select (bank_layout)
+    thr,          # [K·C, 1]
+    lut_q8,       # [W, K·C] int8, transposed
+    scales,       # [1, K·C] f32 dequant factors, broadcast per centroid
+    bias,         # [W, 1]
+    *,
+    depth: int,
+    n_out: int,
+    interpret: bool | None = None,
+    strategy: str = "auto",
 ):
-    if interpret is None:
-        interpret = default_interpret()
-    strategy = resolve_strategy(strategy, interpret)
-    t, k, v = x.shape
-    _, c, n = lut_q8.shape
-    bt, bn, bk = min(block_t, t), min(block_n, n), min(block_k, k)
-    _check_divisible("fuzzy_lut_q8_pallas", T=(t, bt), N=(n, bn), K=(k, bk))
-    n_internal = c - 1
-    grid = (t // bt, n // bn, k // bk)
-    return pl.pallas_call(
-        functools.partial(_q8_kernel, depth=depth, strategy=strategy),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bt, bk, v), lambda i, j, kk: (i, kk, 0)),
-            pl.BlockSpec((bk, n_internal, v), lambda i, j, kk: (kk, 0, 0)),
-            pl.BlockSpec((bk, n_internal), lambda i, j, kk: (kk, 0)),
-            pl.BlockSpec((bk, c, bn), lambda i, j, kk: (kk, 0, j)),
-            pl.BlockSpec((bk,), lambda i, j, kk: (kk,)),
-        ],
-        out_specs=pl.BlockSpec((bt, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((t, n), jnp.float32),
-        compiler_params=_tpu_compiler_params(("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(x, feat_oh, thresholds, lut_q8, scales)
+    """int8 single-bank kernel: LUT bytes quartered in HBM, dequantized
+    in-VMEM. Contract mirrors
+    :func:`repro.kernels.fuzzy_lut.kernel.fuzzy_lut_pallas`."""
+    return bank_call(_bank_step, x, (sel, thr, lut_q8, scales, bias),
+                     depth=depth, n_out=n_out, interpret=interpret,
+                     strategy=strategy, where="fuzzy_lut_q8_pallas")
 
 
 def _stack_q8_kernel(x_ref, sel_ref, thr_ref, lut_ref, scale_ref, bias_ref,

@@ -602,7 +602,7 @@ class MultiModelServer:
                 ``"reject"`` raises :class:`QueueFullError` at submit,
                 ``"block"`` parks the submitter until space frees.
             **build_kw: forwarded to ``build_plan`` (``fuse``,
-                ``bucket_sizes``, ``block_t``, ... — see its docstring).
+                ``bucket_sizes``, ``strategy``, ... — see its docstring).
 
         Raises:
             ValueError: unknown ``priority``/``policy``, or

@@ -921,8 +921,9 @@ def test_donated_inputs_never_invalidate_caller_arrays(ds):
 
 
 def test_ops_layout_memo_pads_static_operands_once():
-    """Satellite: the ops.py wrappers must not re-pad lut/thr/feat_oh per
-    call — one layout build per (layer, geometry), cache hits after."""
+    """Satellite: the ops.py wrappers must not rebuild the kernel layout of
+    lut/thr/feat_oh per call — one layout build per (layer, q8?), cache
+    hits after."""
     from repro.core.amm import init_pegasus_linear
     from repro.kernels.fuzzy_lut.ops import (
         LAYOUT_STATS, fuzzy_lut_matmul, fuzzy_lut_matmul_q8)
@@ -931,19 +932,19 @@ def test_ops_layout_memo_pads_static_operands_once():
     layer = init_pegasus_linear(
         rng.normal(size=(24, 10)).astype(np.float32), None,
         rng.normal(size=(256, 24)).astype(np.float32), group_size=4, depth=3,
-        lut_bits=None)                          # K=6, N=10: NOT block-divisible
+        lut_bits=None)                          # K=6, N=10: both padded
     x = jnp.asarray(rng.normal(size=(5, 24)).astype(np.float32))
-    fuzzy_lut_matmul(layer, x, block_t=8, block_n=8, block_k=4)
+    fuzzy_lut_matmul(layer, x)
     builds = LAYOUT_STATS["layout_builds"]
     hits = LAYOUT_STATS["cache_hits"]
-    fuzzy_lut_matmul(layer, x, block_t=8, block_n=8, block_k=4)
-    fuzzy_lut_matmul(layer, x[:3], block_t=8, block_n=8, block_k=4)
+    fuzzy_lut_matmul(layer, x)
+    fuzzy_lut_matmul(layer, x[:3])
     assert LAYOUT_STATS["layout_builds"] == builds       # no re-pad per call
     assert LAYOUT_STATS["cache_hits"] >= hits + 2
     # the q8 wrapper keeps its own (quantized) layout entry
-    fuzzy_lut_matmul_q8(layer, x, block_t=8, block_n=8, block_k=4)
+    fuzzy_lut_matmul_q8(layer, x)
     builds_q8 = LAYOUT_STATS["layout_builds"]
-    fuzzy_lut_matmul_q8(layer, x, block_t=8, block_n=8, block_k=4)
+    fuzzy_lut_matmul_q8(layer, x)
     assert LAYOUT_STATS["layout_builds"] == builds_q8
 
 

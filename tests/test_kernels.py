@@ -9,7 +9,9 @@ import pytest
 from repro.core import init_pegasus_linear
 from repro.core.amm import apply_gather
 from repro.core.fuzzy_tree import fit_tree, stack_trees
-from repro.kernels.fuzzy_lut.kernel import fuzzy_lut_pallas, stack_layout
+from repro.kernels.fuzzy_lut.kernel import (
+    bank_layout, fuzzy_lut_pallas, fuzzy_lut_stack_pallas, stack_layout,
+)
 from repro.kernels.fuzzy_lut.ops import fuzzy_lut_matmul, prepare_feat_onehot
 from repro.kernels.fuzzy_lut.ref import fuzzy_lut_matmul_ref, tree_descent_ref
 
@@ -33,27 +35,34 @@ def _random_problem(rng, t, k, v, depth, n, lut_dtype=jnp.float32):
     return x, trees, lut
 
 
+def _single(x, trees, lut, bias=None, **kw):
+    """The single-bank kernel on ``[T, K, v]`` activations, its operands in
+    the plan-build layout (``bank_layout``)."""
+    k, c, n = lut.shape
+    v = x.shape[-1]
+    bias = jnp.zeros(n) if bias is None else bias
+    ops = bank_layout(prepare_feat_onehot(trees.features, v), trees.thresholds,
+                      lut, bias)
+    return fuzzy_lut_pallas(x.reshape(x.shape[0], -1), *ops,
+                            depth=c.bit_length() - 1, n_out=n, **kw)
+
+
 SHAPE_SWEEP = [
-    # t, k, v, depth, n, (bt, bn, bk)
-    (8, 2, 2, 1, 4, (8, 4, 2)),
-    (16, 4, 4, 2, 8, (8, 8, 2)),
-    (32, 8, 4, 3, 16, (16, 16, 4)),
-    (64, 16, 8, 4, 32, (32, 32, 8)),
-    pytest.param(128, 32, 4, 4, 64, (64, 64, 16), marks=pytest.mark.slow),
-    pytest.param(256, 64, 2, 5, 128, (128, 128, 32), marks=pytest.mark.slow),
+    # t, k, v, depth, n
+    (8, 2, 2, 1, 4),
+    (16, 4, 4, 2, 8),
+    (32, 8, 4, 3, 16),
+    (64, 16, 8, 4, 32),
+    pytest.param(128, 32, 4, 4, 64, marks=pytest.mark.slow),
+    pytest.param(256, 64, 2, 5, 128, marks=pytest.mark.slow),
 ]
 
 
-@pytest.mark.parametrize("t,k,v,depth,n,blocks", SHAPE_SWEEP)
-def test_kernel_matches_oracle_shape_sweep(t, k, v, depth, n, blocks):
+@pytest.mark.parametrize("t,k,v,depth,n", SHAPE_SWEEP)
+def test_kernel_matches_oracle_shape_sweep(t, k, v, depth, n):
     rng = np.random.default_rng(t * 1000 + k)
     x, trees, lut = _random_problem(rng, t, k, v, depth, n)
-    feat_oh = prepare_feat_onehot(trees.features, v)
-    bt, bn, bk = blocks
-    got = fuzzy_lut_pallas(
-        x, feat_oh, trees.thresholds, lut,
-        depth=depth, block_t=bt, block_n=bn, block_k=bk,
-    )
+    got = _single(x, trees, lut)
     want = fuzzy_lut_matmul_ref(x, trees.features, trees.thresholds, lut)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
@@ -62,37 +71,85 @@ def test_kernel_matches_oracle_shape_sweep(t, k, v, depth, n, blocks):
 def test_kernel_dtype_sweep(lut_dtype):
     rng = np.random.default_rng(11)
     x, trees, lut = _random_problem(rng, 32, 8, 4, 4, 16, lut_dtype=lut_dtype)
-    feat_oh = prepare_feat_onehot(trees.features, 4)
-    got = fuzzy_lut_pallas(
-        x, feat_oh, trees.thresholds, lut, depth=4,
-        block_t=16, block_n=16, block_k=4,
-    )
+    got = _single(x, trees, lut)
     want = fuzzy_lut_matmul_ref(x, trees.features, trees.thresholds, lut)
     tol = 1e-5 if lut_dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=tol, atol=tol)
 
 
 def test_kernel_accumulation_over_k_blocks():
-    """K-innermost accumulation must equal single-block result."""
+    """Group-innermost accumulation must equal the single-block result: at
+    RNN-B's recurrent width (K=24 groups of 1, depth 8) the single-bank
+    kernel runs 3 group tiles of 8 (``bank_tiles``) and 2 batch tiles; the
+    stacked kernel with L=1 runs the whole bank in one step. Both
+    strategies."""
     rng = np.random.default_rng(13)
-    x, trees, lut = _random_problem(rng, 16, 8, 4, 3, 8)
-    feat_oh = prepare_feat_onehot(trees.features, 4)
-    one = fuzzy_lut_pallas(x, feat_oh, trees.thresholds, lut, depth=3,
-                           block_t=16, block_n=8, block_k=8)
-    many = fuzzy_lut_pallas(x, feat_oh, trees.thresholds, lut, depth=3,
-                            block_t=16, block_n=8, block_k=2)
-    np.testing.assert_allclose(np.asarray(one), np.asarray(many), rtol=1e-5, atol=1e-5)
+    k, depth, n, t = 24, 8, 24, 256
+    c = 2 ** depth
+    feats = jnp.zeros((k, c - 1), jnp.int32)
+    thr = jnp.asarray(rng.normal(size=(k, c - 1)), jnp.float32)
+    lut = jnp.asarray(rng.normal(size=(k, c, n)), jnp.float32)
+    bias = jnp.asarray(rng.normal(size=n), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(t, k)), jnp.float32)
+    feat_oh = prepare_feat_onehot(feats, 1)
+    for strategy in ("lookup", "mxu"):
+        many = fuzzy_lut_pallas(x, *bank_layout(feat_oh, thr, lut, bias),
+                                depth=depth, n_out=n, strategy=strategy)
+        one = fuzzy_lut_stack_pallas(
+            x, *stack_layout(feat_oh[None], thr[None], lut[None], bias[None]),
+            depth=depth, ks=(k,), n_out=n, block_t=t, strategy=strategy)
+        np.testing.assert_allclose(np.asarray(one), np.asarray(many),
+                                   rtol=1e-5, atol=1e-5, err_msg=strategy)
+
+
+# RNN-B's banks (v=1, depth 8): input (K=2, N=24), recurrent (K=24, N=24)
+# and classifier (K=24, N=3)
+RNN_B_BANKS = [(2, 24), (24, 24), (24, 3)]
+
+
+@pytest.mark.parametrize("strategy", ["lookup", "mxu"])
+@pytest.mark.parametrize("k,n", RNN_B_BANKS)
+def test_single_bank_matches_gather_at_rnn_b_widths(k, n, strategy):
+    """The plan's single-bank path at RNN-B's widths ≡ the ``gather``
+    backend, on both kernel backends, for 600 flows: not a multiple of the
+    batch tile (512 at K=2, 128 at K=24), and K=24 spans 3 group tiles."""
+    from repro.core.amm import PegasusLinear
+    from repro.core.fuzzy_tree import FuzzyTree
+    from repro.engine.plan import CompiledBank
+    from repro.kernels.fuzzy_lut.quantized import (
+        fuzzy_lut_q8_ref, quantize_lut_int8,
+    )
+
+    rng = np.random.default_rng(100 * k + n)
+    c, t = 256, 600
+    feats = jnp.zeros((k, c - 1), jnp.int32)
+    thr = jnp.asarray(rng.normal(size=(k, c - 1)), jnp.float32)
+    lut = jnp.asarray(rng.normal(size=(k, c, n)), jnp.float32)
+    bias = jnp.asarray(rng.normal(size=n), jnp.float32)
+    trees = FuzzyTree(feats, thr, jnp.zeros((k, c, 1), jnp.float32))
+    bank = CompiledBank(PegasusLinear(trees, lut, bias, group_size=1),
+                        interpret=True, strategy=strategy)
+    assert bank.tiles == ((512, 2) if k == 2 else (128, 8))
+    x = jnp.asarray(rng.normal(size=(t, k)), jnp.float32)
+    np.testing.assert_allclose(np.asarray(bank.apply(x, "kernel")),
+                               np.asarray(bank.apply(x, "gather")),
+                               rtol=1e-5, atol=1e-5)
+    q8, scales = quantize_lut_int8(lut)
+    want = fuzzy_lut_q8_ref(x[:, :, None], feats, thr, q8, scales) + bias
+    np.testing.assert_allclose(np.asarray(bank.apply(x, "kernel_q8")),
+                               np.asarray(want), rtol=1e-5, atol=1e-5)
 
 
 def test_ops_wrapper_pads_ragged_shapes():
-    """T/K/N not divisible by blocks → wrapper pads; result unchanged."""
+    """Ragged T, K and N → the layout and the launcher pad; result
+    unchanged."""
     rng = np.random.default_rng(15)
-    d, n, s = 24, 10, 1024  # K=6 groups of 4 — not a multiple of block_k
+    d, n, s = 24, 10, 1024  # K=6 groups of 4, N=10, T=37: all ragged
     w = rng.normal(size=(d, n)).astype(np.float32)
     calib = rng.normal(size=(s, d)).astype(np.float32)
     layer = init_pegasus_linear(w, None, calib, group_size=4, depth=3, lut_bits=None)
     x = jnp.asarray(calib[:37])  # ragged T
-    got = fuzzy_lut_matmul(layer, x, block_t=16, block_n=8, block_k=4)
+    got = fuzzy_lut_matmul(layer, x)
     want = apply_gather(layer, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
 
@@ -105,7 +162,7 @@ def test_ops_wrapper_batch_dims():
     calib = rng.normal(size=(s, d)).astype(np.float32)
     layer = init_pegasus_linear(w, b, calib, group_size=4, depth=3, lut_bits=None)
     x = jnp.asarray(rng.normal(size=(3, 5, d)).astype(np.float32))
-    got = fuzzy_lut_matmul(layer, x, block_t=8, block_n=8, block_k=4)
+    got = fuzzy_lut_matmul(layer, x)
     want = apply_gather(layer, x)
     assert got.shape == (3, 5, n)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
@@ -141,8 +198,6 @@ def _stack_args(x, feat_oh, thr, lut, bias):
 def test_stack_kernel_matches_chained_single_bank(strategy):
     """The stacked-layer kernel ≡ chaining the single-bank kernel per layer
     (re-partition + bias applied between layers), on both strategies."""
-    from repro.kernels.fuzzy_lut.kernel import fuzzy_lut_stack_pallas
-
     rng = np.random.default_rng(7)
     ks, v, depth, n_out, t = (6, 4, 4, 4), 2, 3, 3, 16
     x, feat_oh, thr, lut, bias = _stacked_problem(
@@ -151,24 +206,20 @@ def test_stack_kernel_matches_chained_single_bank(strategy):
         *_stack_args(x, feat_oh, thr, lut, bias), depth=depth, ks=ks,
         n_out=n_out, strategy=strategy)
 
-    h = x
+    h = x.reshape(t, -1)
     for layer, k in enumerate(ks):
         n = n_out if layer == len(ks) - 1 else ks[layer + 1] * v
         y = fuzzy_lut_pallas(
-            h[:, :k], feat_oh[layer, :k], thr[layer, :k],
-            lut[layer, :k, :, :n], depth=depth, block_t=t, block_n=n,
-            block_k=k, strategy=strategy)
-        y = y + bias[layer, :n]
-        if layer + 1 < len(ks):
-            h = y.reshape(t, ks[layer + 1], v)
+            h[:, :k * v], *bank_layout(feat_oh[layer, :k], thr[layer, :k],
+                                       lut[layer, :k, :, :n], bias[layer, :n]),
+            depth=depth, n_out=n, strategy=strategy)
+        h = y
     np.testing.assert_allclose(np.asarray(got), np.asarray(y),
                                rtol=1e-5, atol=1e-5)
 
 
 def test_stack_kernel_tiles_batch():
     """T larger than block_t: grid-tiled result equals the one-tile result."""
-    from repro.kernels.fuzzy_lut.kernel import fuzzy_lut_stack_pallas
-
     rng = np.random.default_rng(9)
     ks, v, depth, n_out = (4, 4), 2, 3, 8
     args = _stack_args(*_stacked_problem(
@@ -185,17 +236,22 @@ def test_block_divisibility_raises_value_error():
     """Satellite bugfix: mis-padded operands raise ValueError naming the
     offending dims (an assert would vanish under ``python -O`` and the
     engine fallback could never catch it)."""
-    from repro.kernels.fuzzy_lut.kernel import fuzzy_lut_stack_pallas
-
     rng = np.random.default_rng(21)
-    x, trees, lut = _random_problem(rng, 12, 4, 4, 2, 8)   # T=12 vs block 8
-    feat_oh = prepare_feat_onehot(trees.features, 4)
-    with pytest.raises(ValueError, match=r"T=12 % block 8"):
-        fuzzy_lut_pallas(x, feat_oh, trees.thresholds, lut, depth=2,
-                         block_t=8, block_n=8, block_k=4)
-    with pytest.raises(ValueError, match=r"N=8 % block 3"):
-        fuzzy_lut_pallas(x, feat_oh, trees.thresholds, lut, depth=2,
-                         block_t=12, block_n=3, block_k=4)
+    # single bank: groups not padded to the group tile (K=24, C=256 splits
+    # into tiles of 8 groups; 23 is not a multiple), or x wider than W
+    k, depth, n = 24, 8, 24
+    c = 2 ** depth
+    sel, thr, lut, bias = bank_layout(
+        prepare_feat_onehot(jnp.zeros((k, c - 1), jnp.int32), 1),
+        jnp.zeros((k, c - 1)), jnp.zeros((k, c, n)), jnp.zeros(n))
+    x = jnp.zeros((8, k))
+    cut = (k - 1) * c
+    with pytest.raises(ValueError, match=r"K=23 % block 8"):
+        fuzzy_lut_pallas(x, sel[:cut], thr[:cut], lut[:, :cut], bias,
+                         depth=depth, n_out=n)
+    with pytest.raises(ValueError, match=r"x carries 32 features"):
+        fuzzy_lut_pallas(jnp.zeros((8, 32)), sel, thr, lut, bias,
+                         depth=depth, n_out=n)
 
     ks, v, depth, n_out = (4, 4), 4, 2, 8
     args = _stack_args(*_stacked_problem(
@@ -233,11 +289,7 @@ if HAVE_HYPOTHESIS:
     def test_property_kernel_equals_oracle(t, k, v, depth, n, seed):
         rng = np.random.default_rng(seed)
         x, trees, lut = _random_problem(rng, t, k, v, depth, n)
-        feat_oh = prepare_feat_onehot(trees.features, v)
-        got = fuzzy_lut_pallas(
-            x, feat_oh, trees.thresholds, lut, depth=depth,
-            block_t=t, block_n=n, block_k=k,
-        )
+        got = _single(x, trees, lut)
         want = fuzzy_lut_matmul_ref(x, trees.features, trees.thresholds, lut)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
 

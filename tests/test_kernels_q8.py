@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core.fuzzy_tree import fit_tree, stack_trees
+from repro.kernels.fuzzy_lut.kernel import bank_layout
 from repro.kernels.fuzzy_lut.ops import prepare_feat_onehot
 from repro.kernels.fuzzy_lut.quantized import (
     fuzzy_lut_q8_pallas, fuzzy_lut_q8_ref, quantize_lut_int8,
@@ -21,6 +22,16 @@ def _problem(rng, t, k, v, depth, n):
     return x, trees, lut
 
 
+def _single_q8(x, feat_oh, thr, q, s, bias=None, **kw):
+    """The int8 single-bank kernel on ``[T, K, v]`` activations, its
+    operands in the plan-build layout (``bank_layout``)."""
+    k, c, n = q.shape
+    bias = jnp.zeros(n) if bias is None else bias
+    return fuzzy_lut_q8_pallas(
+        x.reshape(x.shape[0], -1), *bank_layout(feat_oh, thr, q, bias, s),
+        depth=c.bit_length() - 1, n_out=n, **kw)
+
+
 def test_quantize_lut_int8_roundtrip():
     rng = np.random.default_rng(0)
     lut = jnp.asarray(rng.normal(size=(4, 16, 8)).astype(np.float32))
@@ -31,18 +42,16 @@ def test_quantize_lut_int8_roundtrip():
     assert rel < 0.01  # int8 symmetric: ~0.4% rms for gaussian
 
 
-@pytest.mark.parametrize("t,k,v,depth,n,blocks", [
-    (16, 4, 4, 3, 8, (16, 8, 2)),
-    (64, 8, 2, 4, 16, (32, 16, 4)),
+@pytest.mark.parametrize("t,k,v,depth,n", [
+    (16, 4, 4, 3, 8),
+    (64, 8, 2, 4, 16),
 ])
-def test_q8_kernel_matches_ref(t, k, v, depth, n, blocks):
+def test_q8_kernel_matches_ref(t, k, v, depth, n):
     rng = np.random.default_rng(t + k)
     x, trees, lut = _problem(rng, t, k, v, depth, n)
     q, s = quantize_lut_int8(lut)
     feat_oh = prepare_feat_onehot(trees.features, v)
-    bt, bn, bk = blocks
-    got = fuzzy_lut_q8_pallas(x, feat_oh, trees.thresholds, q, s,
-                              depth=depth, block_t=bt, block_n=bn, block_k=bk)
+    got = _single_q8(x, feat_oh, trees.thresholds, q, s)
     want = fuzzy_lut_q8_ref(x, trees.features, trees.thresholds, q, s)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -87,11 +96,10 @@ def test_q8_stack_kernel_matches_chained_q8(strategy):
     h = x
     for layer, k in enumerate(ks):
         n = n_out if layer == l - 1 else ks[layer + 1] * v
-        y = fuzzy_lut_q8_pallas(
+        y = _single_q8(
             h[:, :k], args[0][layer, :k], args[1][layer, :k],
-            args[2][layer, :k, :, :n], args[3][layer, :k],
-            depth=depth, block_t=t, block_n=n, block_k=k, strategy=strategy)
-        y = y + args[4][layer, :n]
+            args[2][layer, :k, :, :n], args[3][layer, :k], args[4][layer, :n],
+            strategy=strategy)
         if layer + 1 < l:
             h = y.reshape(t, ks[layer + 1], v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(y),
@@ -104,8 +112,7 @@ def test_q8_close_to_fp32_path():
     x, trees, lut = _problem(rng, 32, 8, 4, 4, 16)
     q, s = quantize_lut_int8(lut)
     feat_oh = prepare_feat_onehot(trees.features, 4)
-    got = fuzzy_lut_q8_pallas(x, feat_oh, trees.thresholds, q, s,
-                              depth=4, block_t=32, block_n=16, block_k=8)
+    got = _single_q8(x, feat_oh, trees.thresholds, q, s)
     want = fuzzy_lut_matmul_ref(x, trees.features, trees.thresholds, lut)
     rel = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
     assert rel < 0.01, rel

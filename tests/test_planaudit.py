@@ -18,7 +18,8 @@ import pytest
 from repro.analysis.planaudit import (AuditConfig, PlanAuditError,
                                       accumulation_grid, audit_plan,
                                       overflow_bound)
-from repro.core.amm import init_pegasus_linear
+from repro.core.amm import PegasusLinear, init_pegasus_linear
+from repro.core.fuzzy_tree import FuzzyTree
 from repro.dataplane.resources import SwitchBudget
 from repro.engine import PlanRegistry, build_plan, plan_for
 from repro.kernels.fuzzy_lut.quantized import quantize_lut_int8
@@ -111,7 +112,7 @@ def test_pga102_tampered_q8_table():
     plan = build_plan(_chain_banks(1), audit="off")
     assert not _rules(audit_plan(plan), "PGA102")
     # zero one bank's int8 table: dequant error becomes ~the group amax
-    plan.banks[1].lut_q8_p = jnp.zeros_like(plan.banks[1].lut_q8_p)
+    plan.banks[1].lut_q8 = jnp.zeros_like(plan.banks[1].lut_q8)
     found = _rules(audit_plan(plan), "PGA102")
     assert found and found[0].severity == "error"
     assert found[0].site == "bank[1]"
@@ -134,6 +135,21 @@ def test_pga103_vmem_budget():
     need = max(f.metrics["bytes"] for f in found)
     rep = audit_plan(plan, AuditConfig(vmem_budget_bytes=int(need * 1.5)))
     assert any(f.severity == "warning" for f in _rules(rep, "PGA103"))
+    # a single bank split over group tiles is priced per grid step: RNN-B's
+    # recurrent width (K=24 groups of 1, C=256) runs 8 groups × 128 flows,
+    # [24, 128] activation blocks, [2048, 24] select and [24, 2048] LUT
+    k, c, n = 24, 256, 24
+    trees = FuzzyTree(jnp.zeros((k, c - 1), jnp.int32),
+                      jnp.zeros((k, c - 1)), jnp.zeros((k, c, 1)))
+    wide = build_plan(PegasusLinear(trees, jnp.zeros((k, c, n)), None,
+                                    group_size=1), audit="off")
+    (f,) = _rules(audit_plan(wide, AuditConfig(vmem_budget_bytes=4096)),
+                  "PGA103")
+    assert f.metrics["shape"] == "K=24, Kt=8, W=24, bt=128"
+    nodes, w, bt = 8 * c, 24, 128
+    floats = (2 * w * bt + nodes * (w + 1) + w * nodes + nodes + w
+              + 4 * nodes * bt)
+    assert f.metrics["bytes"] == 4 * floats + w * nodes
 
 
 # ---------------------------------------------------------------------------
@@ -142,22 +158,23 @@ def test_pga103_vmem_budget():
 
 
 def test_pga104_hidden_pad_rows_and_mxu_lanes():
-    # bucket 384 vs single-bank tile 256: 128 hidden rows per call
-    plan = build_plan(_chain_banks(4), fuse=False, block_t=256,
-                      bucket_sizes=(8, 384), audit="off")
+    # bucket 1536 vs single-bank batch tile 1024 (K·C = 32 nodes): 512
+    # hidden rows per call
+    plan = build_plan(_chain_banks(4), fuse=False,
+                      bucket_sizes=(8, 1536), audit="off")
+    assert {b.tiles[0] for b in plan.banks} == {1024}
     found = _rules(audit_plan(plan), "PGA104")
     hidden = [f for f in found if f.metrics.get("hidden_rows")]
-    assert hidden and hidden[0].metrics["hidden_rows"] == 128
+    assert hidden and hidden[0].metrics["hidden_rows"] == 512
     assert hidden[0].severity == "warning"
     # power-of-two ladder: no hidden padding
     clean = build_plan(_chain_banks(4), audit="off")
     assert not _rules(audit_plan(clean), "PGA104")
-    # mxu strategy with narrow LUT tiles: lane-alignment warnings
+    # mxu strategy, narrow banks: the batch rides the lanes, so no lane
+    # finding whatever the LUT width
     mxu = build_plan(_chain_banks(4), strategy="mxu", fuse=False,
                      audit="off")
-    lanes = [f for f in _rules(audit_plan(mxu), "PGA104")
-             if "lanes" in f.metrics]
-    assert lanes and all(f.metrics["width"] % 128 for f in lanes)
+    assert not _rules(audit_plan(mxu), "PGA104")
 
 
 # ---------------------------------------------------------------------------

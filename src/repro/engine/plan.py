@@ -995,8 +995,6 @@ def _cnn_plan(model, backend, kw, buckets, fuse, nmax_cap,
 
 
 def _cnn_l_plan(model, backend, kw, buckets, devices=None) -> ExecutionPlan:
-    from repro.nets.cnn import _packet_feats
-
     bank1 = CompiledBank(model.bank1, **kw)
     bank2 = CompiledBank(model.bank2, **kw)
     state = {
@@ -1007,15 +1005,16 @@ def _cnn_l_plan(model, backend, kw, buckets, devices=None) -> ExecutionPlan:
         "bias": jnp.asarray(model.bias),
     }
 
-    def forward(apply, state, seq, payload):
-        x = _packet_feats(seq, payload) * 255.0        # [B, W, 62]
+    def forward(apply, state, x):
+        # x: the packed per-packet records [B, W, 62] (nets.cnn.pack_packets)
         b, w, d = x.shape
-        h_pre = apply(state["b1"], x.reshape(-1, d))
+        h_pre = apply(state["b1"], x.reshape(-1, d).astype(jnp.float32))
         e_pre = apply(state["b2"], h_pre)
-        emb = jnp.tanh(e_pre)
-        idx = hard_index(state["emb_tree"], emb)
-        contrib = state["logit_lut"][idx].reshape(b, w, -1)
-        return contrib.sum(axis=1) + state["bias"]
+        # the second NAM level, under one name on the device trace
+        with jax.named_scope("cnn_l.index"):
+            idx = hard_index(state["emb_tree"], jnp.tanh(e_pre))
+            contrib = state["logit_lut"][idx].reshape(b, w, -1)
+            return contrib.sum(axis=1) + state["bias"]
 
     return ExecutionPlan([bank1, bank2], forward, state, backend=backend,
                          family="cnn_l", bucket_sizes=buckets,

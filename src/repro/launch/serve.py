@@ -719,8 +719,9 @@ class MultiModelServer:
         Args:
             request: the typed request — model name, input arrays (each
                 with a LEADING BATCH DIM; wrap a single flow as
-                ``x[None]``; multi-input models like CNN-L pass an input
-                tuple), optional ``deadline_ms`` budget, and per-request
+                ``x[None]``; a model of several inputs takes a tuple; the
+                dtype is kept, so CNN-L's uint8 records stay 1 byte per
+                value), optional ``deadline_ms`` budget, and per-request
                 ``priority`` (queue-jump within this model's queue — see
                 :data:`~repro.launch.scheduler.PRIORITY_RANK`). The legacy
                 ``submit(name, *inputs, deadline_ms=...)`` shape still

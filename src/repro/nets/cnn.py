@@ -36,6 +36,7 @@ __all__ = [
     "CNNModel", "train_cnn", "cnn_apply",
     "pegasusify_cnn", "pegasus_cnn_apply",
     "CNNL", "train_cnn_l", "cnn_l_apply", "pegasusify_cnn_l", "pegasus_cnn_l_apply",
+    "PACKET_FEATURES", "pack_packets",
 ]
 
 
@@ -205,11 +206,24 @@ def init_cnn_l(num_classes: int, emb_dim: int = 16, seed: int = 0) -> dict:
     }
 
 
+#: bytes of one packet's record: 60 payload bytes, then length, then IPD
+PACKET_FEATURES = 62
+
+
+def pack_packets(seq, payload):
+    """``seq [B, W, 2]`` (length, IPD) and ``payload [B, W, 60]`` → the
+    packed per-packet record ``[B, W, 62]`` uint8: the payload bytes, then
+    the length, then the IPD, as a switch's parser emits one record per
+    packet. The one input CNN-L's plan takes. NumPy in, NumPy out; a jax
+    array in either place gives a jax array."""
+    xp = jnp if isinstance(seq, jax.Array) or isinstance(payload, jax.Array) else np
+    return xp.concatenate([xp.asarray(payload).astype(xp.uint8),
+                           xp.asarray(seq).astype(xp.uint8)], axis=-1)
+
+
 def _packet_feats(seq: jax.Array, payload: jax.Array) -> jax.Array:
     """[B,W,2]+[B,W,60] → [B, W, 62] float in [0,1]."""
-    return jnp.concatenate(
-        [payload.astype(jnp.float32), seq.astype(jnp.float32)], axis=-1
-    ) / 255.0
+    return jnp.asarray(pack_packets(seq, payload), jnp.float32) / 255.0
 
 
 def cnn_l_apply(m_or_p, seq: jax.Array, payload: jax.Array) -> jax.Array:
@@ -241,7 +255,9 @@ def train_cnn_l(
 @dataclasses.dataclass
 class PegasusCNNL:
     """Two-level NAM: per-packet encoder banks → fuzzy index (stored per
-    flow, 4–8 bits, the §7.3 flow-storage trick) → logit LUT, final SumReduce."""
+    flow, 4–8 bits, the §7.3 flow-storage trick) → logit LUT, final SumReduce.
+    Its plan takes the packed per-packet records ``[B, W, 62]`` uint8 of
+    :func:`pack_packets`."""
 
     bank1: PegasusLinear           # raw 62 bytes → encoder layer-1 pre-act
     bank2: PegasusLinear           # layer-1 pre-act → embedding pre-act (ReLU folded)
@@ -256,10 +272,11 @@ def pegasusify_cnn_l(
     enc_group: int = 1, enc_depth: int = 8, index_bits: int = 4,
 ) -> PegasusCNNL:
     p = m.params
-    x = np.asarray(_packet_feats(jnp.asarray(seq), jnp.asarray(payload)))  # [B,W,62]
-    flat = x.reshape(-1, 62) * 255.0  # raw byte domain for the tables
+    # raw byte domain for the tables: the packed records the plan reads
+    flat = np.asarray(pack_packets(seq, payload), np.float32).reshape(
+        -1, PACKET_FEATURES)
 
-    # level-1 bank: raw packet bytes (31 groups × 2 bytes) → layer-1 pre-act
+    # level-1 bank: raw packet bytes (groups of enc_group bytes) → layer-1 pre-act
     bank1 = init_pegasus_linear(
         np.asarray(p["w_e1"], np.float32) / 255.0, np.asarray(p["b_e1"], np.float32),
         flat, group_size=enc_group, depth=enc_depth, lut_bits=None,
@@ -290,6 +307,7 @@ def pegasus_cnn_l_apply(
     backend: str = "gather", jit: bool = False
 ) -> jax.Array:
     """Deployment forward via the engine: all-table encoding → fuzzy index →
-    LUT sum (the two-level NAM). Eager by default — one-shot evaluation
+    LUT sum (the two-level NAM), on the packed record of
+    :func:`pack_packets`. Eager by default — one-shot evaluation
     entry point; serving call sites get the jitted path."""
-    return plan_for(peg)(seq, payload, backend=backend, jit=jit)
+    return plan_for(peg)(pack_packets(seq, payload), backend=backend, jit=jit)

@@ -60,14 +60,14 @@ def _cnn(ds):
 
 
 def _cnn_l(ds):
-    from repro.nets.cnn import pegasusify_cnn_l, train_cnn_l
+    from repro.nets.cnn import pack_packets, pegasusify_cnn_l, train_cnn_l
 
     m = train_cnn_l(ds.train["seq"], ds.train["bytes"], ds.train["label"],
                     ds.num_classes, steps=STEPS)
     peg = pegasusify_cnn_l(m, ds.train["seq"], ds.train["bytes"],
                            enc_depth=4, index_bits=3)
-    return peg, (jnp.asarray(ds.test["seq"][:BATCH]),
-                 jnp.asarray(ds.test["bytes"][:BATCH]))
+    return peg, (jnp.asarray(pack_packets(ds.test["seq"][:BATCH],
+                                          ds.test["bytes"][:BATCH])),)
 
 
 def _ae(ds):

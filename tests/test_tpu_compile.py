@@ -1,20 +1,23 @@
-"""Compile the Pallas kernels and the MLP-B and RNN-B plans for a described
-TPU v5e.
+"""Compile the Pallas kernels and the MLP-B, RNN-B and CNN-L plans for a
+described TPU v5e.
 
 Interpret mode on the CPU runs the kernel bodies but never asks Mosaic, the
 TPU kernel compiler, whether it accepts them. These tests do, with no chip
 attached: the TPU compiler compiles for a ``v5e:2x2`` topology that is only
 described, with ``interpret=False`` and ``strategy="mxu"`` at MLP-B's
-widths (banks ks=(8, 16, 16, 16), v=2, depth 6, hidden 32, 3 classes) and
+widths (banks ks=(8, 16, 16, 16), v=2, depth 6, hidden 32, 3 classes),
 RNN-B's (16 unfused banks of K=2 or 24 groups of 1, depth 8, hidden 24, 3
-classes). A kernel that Mosaic refuses fails here instead of on the chip,
+classes) and CNN-L's (per packet, 62 bytes → 64 → 16 in two unfused banks
+of groups of 1 at depth 8, a 4-bit index, 3 classes). A kernel that Mosaic refuses fails here instead of on the chip,
 where the servers' breakers would quietly fall back to the ``gather`` plan.
 
 The topology is described inside a fixture, so only the worker that runs
 this file loads the TPU compiler; it skips only when the description fails.
 """
 
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +27,7 @@ import pytest
 from repro.core.amm import PegasusLinear
 from repro.core.fuzzy_tree import FuzzyTree
 from repro.engine import build_plan
+from repro.nets.cnn import PACKET_FEATURES, PegasusCNNL
 from repro.nets.rnn import PegasusRNN
 from repro.kernels.fuzzy_lut.kernel import fuzzy_lut_pallas, fuzzy_lut_stack_pallas
 from repro.kernels.fuzzy_lut.quantized import (
@@ -163,20 +167,22 @@ def test_mlp_b_plan_forward_compiles_for_v5e(one_chip, backend):
     assert grids == [((4,), (W, 256))]
 
 
+def _depth8_bank(rng, k: int, n: int) -> PegasusLinear:
+    """A random bank of ``k`` groups of 1, depth 8, ``n`` outputs."""
+    trees = FuzzyTree(
+        jnp.zeros((k, RNN_C - 1), jnp.int32),
+        jnp.asarray(rng.normal(size=(k, RNN_C - 1)), jnp.float32),
+        jnp.zeros((k, RNN_C, 1), jnp.float32))
+    return PegasusLinear(
+        trees, jnp.asarray(rng.normal(size=(k, RNN_C, n)), jnp.float32),
+        jnp.asarray(rng.normal(size=n), jnp.float32), group_size=1)
+
+
 def _rnn_b_model() -> PegasusRNN:
     """Random banks at RNN-B's widths: 8 input banks (K=2 → 24), 7
     recurrent (K=24 → 24) and the classifier (K=24 → 3)."""
     rng = np.random.default_rng(0)
-
-    def bank(k, n):
-        trees = FuzzyTree(
-            jnp.zeros((k, RNN_C - 1), jnp.int32),
-            jnp.asarray(rng.normal(size=(k, RNN_C - 1)), jnp.float32),
-            jnp.zeros((k, RNN_C, 1), jnp.float32))
-        return PegasusLinear(
-            trees, jnp.asarray(rng.normal(size=(k, RNN_C, n)), jnp.float32),
-            jnp.asarray(rng.normal(size=n), jnp.float32), group_size=1)
-
+    bank = functools.partial(_depth8_bank, rng)
     return PegasusRNN(x_banks=[bank(2, RNN_HIDDEN) for _ in range(8)],
                       h_banks=[bank(RNN_HIDDEN, RNN_HIDDEN) for _ in range(7)],
                       out_bank=bank(RNN_HIDDEN, N_OUT), window=8)
@@ -207,3 +213,85 @@ def test_rnn_b_plan_forward_compiles_for_v5e(one_chip):
         lambda s, xx: plan._jit(s, (xx,), backend="kernel"), state, x)
     assert sorted(set(grids)) == [((8, 1), (24, 512)), ((32, 3), (24, 128))]
     assert sorted(g[0] for g in grids).count((32, 3)) == 8
+
+
+def _cnn_l_model() -> PegasusCNNL:
+    """Random banks at CNN-L's widths: per packet 62 bytes → 64 → 16, depth
+    8, and a depth-4 index tree over the 16-dim embedding."""
+    rng = np.random.default_rng(0)
+    tree = FuzzyTree(jnp.asarray(rng.integers(0, 16, 15), jnp.int32),
+                     jnp.asarray(rng.normal(size=15), jnp.float32),
+                     jnp.zeros((16, 16), jnp.float32))
+    return PegasusCNNL(bank1=_depth8_bank(rng, PACKET_FEATURES, 64),
+                       bank2=_depth8_bank(rng, 64, 16),
+                       emb_tree=tree,
+                       logit_lut=jnp.asarray(rng.normal(size=(16, N_OUT)),
+                                             jnp.float32),
+                       bias=jnp.zeros(N_OUT, jnp.float32), index_bits=4)
+
+
+@pytest.fixture(scope="module")
+def cnn_l_forward(one_chip):
+    """CNN-L's plan, its state and input as shapes on the described chip,
+    and the text of its jitted forward compiled at bucket 4096."""
+    plan = build_plan(_cnn_l_model(), backend="kernel", interpret=False,
+                      audit="off")
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    state = jax.tree.map(sds, plan._state)
+    x = jax.ShapeDtypeStruct((4096, 8, PACKET_FEATURES), jnp.uint8,
+                             sharding=one_chip)
+    text = plan._jit.lower(state, (x,), backend="kernel").compile().as_text()
+    return plan, state, x, text
+
+
+def test_cnn_l_plan_forward_compiles_for_v5e(cnn_l_forward):
+    """CNN-L's jitted plan forward at bucket 4096 on the packed uint8
+    records, as a server runs it: two lane-dense single-bank kernels over
+    the 32768 packet rows of a chunk, named ``fuzzy_lut_pallas``. Both
+    banks (K = 62 padded to 64, and K = 64; W = 64) run 128-flow tiles ×
+    8 group tiles of 8 groups."""
+    plan, state, x, text = cnn_l_forward
+    calls = [line.split(" = ", 1)[0].strip().lstrip("%")
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    assert all(c.rsplit(".", 1)[0] == "fuzzy_lut_pallas" for c in calls)
+    grids = _pallas_grids(
+        lambda s, xx: plan._jit(s, (xx,), backend="kernel"), state, x)
+    assert grids == [((256, 8), (64, 128))] * 2
+
+
+def test_cnn_l_index_ops_are_found_by_name(cnn_l_forward):
+    """The chip's trace names a device operation by its HLO instruction
+    text (operands included, metadata left out), with no name stack, so
+    the benchmark's ``cnn_l_index_share`` finds the forward's
+    ``cnn_l.index`` scope by instruction names. In the forward compiled for
+    a v5e, every top-level instruction whose text its patterns match is in
+    the scope, every fusion in the scope is matched, and what the scope
+    holds besides is no larger than one value per packet row."""
+    from bench.metrics.cnn_l_index_share import PATTERNS
+
+    entry = cnn_l_forward[3].split("\nENTRY ", 1)[1]
+    matched = 0
+    for line in entry.splitlines()[1:]:
+        if " = " not in line:
+            continue
+        name, rest = line.strip().removeprefix("ROOT ").split(" = ", 1)
+        op = re.search(r'op_name="([^"]*)"', rest)
+        in_scope = op is not None and "cnn_l.index" in op.group(1)
+        opcode = re.search(r"\s([a-z][\w-]*)\(", rest).group(1)
+        if opcode in ("bitcast", "get-tuple-element", "parameter",
+                      "constant"):
+            continue                  # views and literals: no device work
+        if any(p in f"{name} = {rest.split(', metadata=')[0]}"
+               for p in PATTERNS):
+            assert in_scope, line
+            matched += 1
+        elif in_scope:
+            assert opcode != "fusion", line
+            dims = re.match(r"[a-z]\w*\[([\d,]*)\]", rest).group(1)
+            assert np.prod([int(d) for d in dims.split(",") if d]) <= 32768, line
+    assert matched > 0

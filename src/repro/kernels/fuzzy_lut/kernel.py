@@ -4,8 +4,10 @@ TPU-native realization of the paper's table lookup:
 
   * The clustering-tree descent is *gather-free*: feature selection becomes a
     one-hot matmul (the one-hot is precomputed offline), node selection a
-    one-hot reduction — every step is VPU compare/select or MXU matmul, so
-    the whole "fuzzy match" is branchless and systolic-friendly.
+    one-hot reduction over only the current level's nodes (a static window
+    of whole 8-row tiles, :func:`descent_windows`) — every step is VPU
+    compare/select or MXU matmul, so the whole "fuzzy match" is branchless
+    and systolic-friendly.
   * The Map (leaf→row lookup) + SumReduce (Σ over groups) pair is ONE MXU
     matmul: ``LUT^T: [W, K·C] @ onehot(leaf): [K·C, Tt]`` — the same
     primitive-fusion insight as the paper's, re-expressed for a systolic
@@ -81,8 +83,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["bank_layout", "bank_tiles", "default_interpret",
-           "fuzzy_lut_kernel", "fuzzy_lut_pallas", "fuzzy_lut_stack_pallas",
-           "resolve_strategy", "stack_block_t", "stack_layout"]
+           "descent_windows", "fuzzy_lut_kernel", "fuzzy_lut_pallas",
+           "fuzzy_lut_stack_pallas", "resolve_strategy", "stack_block_t",
+           "stack_layout"]
 
 # Byte budget of ONE [K·C, Tt] f32 descent intermediate (values, bits,
 # one-hots) of either kernel; the batch and group tiles follow from it.
@@ -241,9 +244,22 @@ def bank_layout(feat_oh, thr, lut, bias, scales=None) -> tuple:
 # ---------------------------------------------------------------------------
 
 
+def descent_windows(depth: int) -> tuple[tuple[int, int], ...]:
+    """Static row window ``(lo, hi)`` of each level of a heap-ordered tree
+    of depth ``depth`` over ``C = 2**depth`` node rows: level ``l``'s nodes
+    ``2**l - 1 … 2**(l+1) - 2``, rounded out to whole 8-row sublane tiles
+    and clamped to C. At depth 8 the descent touches 312 of 8 × 256 rows."""
+    c = 1 << depth
+    return tuple(((2 ** level - 1) // 8 * 8,
+                  min(c, -(-(2 ** (level + 1) - 1) // 8) * 8))
+                 for level in range(depth))
+
+
 def _stack_layer_mxu(h, sel, thr, tab, *, depth: int):
     """One bank in the transposed layout, gather-free: ``h [W, Tt]`` →
-    ``[W, Tt]`` contributions (bias not yet added)."""
+    ``[W, Tt]`` contributions (bias not yet added). Each level's
+    compare-select-sum reads only that level's window of node rows
+    (:func:`descent_windows`); ``node`` stays the absolute heap index."""
     kc, tt = sel.shape[0], h.shape[1]
     c = 1 << depth
     k = kc // c
@@ -251,11 +267,13 @@ def _stack_layer_mxu(h, sel, thr, tab, *, depth: int):
         sel, h, (((1,), (0,)), ((), ())), precision=_HIGHEST,
         preferred_element_type=jnp.float32)
     bits = (vals > thr).astype(jnp.int32).reshape(k, c, tt)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (k, c, tt), 1)
     node = jnp.zeros((k, 1, tt), jnp.int32)
-    for _ in range(depth):
-        bit = jnp.sum(jnp.where(iota == node, bits, 0), axis=1, keepdims=True)
+    for lo, hi in descent_windows(depth):
+        rows = jax.lax.broadcasted_iota(jnp.int32, (k, hi - lo, tt), 1)
+        bit = jnp.sum(jnp.where(rows == node - lo, bits[:, lo:hi], 0),
+                      axis=1, keepdims=True)
         node = 2 * node + 1 + bit
+    iota = jax.lax.broadcasted_iota(jnp.int32, (k, c, tt), 1)
     onehot = (iota == node - (c - 1)).astype(jnp.float32).reshape(kc, tt)
     return jax.lax.dot_general(                    # [W, Tt]: Σ_k lut[k, leaf]
         tab, onehot, (((1,), (0,)), ((), ())), precision=_HIGHEST,

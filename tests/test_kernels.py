@@ -10,7 +10,8 @@ from repro.core import init_pegasus_linear
 from repro.core.amm import apply_gather
 from repro.core.fuzzy_tree import fit_tree, stack_trees
 from repro.kernels.fuzzy_lut.kernel import (
-    bank_layout, fuzzy_lut_pallas, fuzzy_lut_stack_pallas, stack_layout,
+    bank_layout, descent_windows, fuzzy_lut_pallas, fuzzy_lut_stack_pallas,
+    stack_layout,
 )
 from repro.kernels.fuzzy_lut.ops import fuzzy_lut_matmul, prepare_feat_onehot
 from repro.kernels.fuzzy_lut.ref import fuzzy_lut_matmul_ref, tree_descent_ref
@@ -262,6 +263,88 @@ def test_block_divisibility_raises_value_error():
     with pytest.raises(ValueError, match="ks has 3 entries"):
         fuzzy_lut_stack_pallas(*args, depth=depth,
                                ks=(4, 4, 4), n_out=n_out, block_t=12)
+
+
+# the row window of each level of a depth-8 descent, as (lo, hi); a tree of
+# depth d >= 3 takes the first d, and one of depth <= 3 (C <= 8) reads the
+# whole C at every level
+DESCENT_WINDOWS = [(0, 8), (0, 8), (0, 8), (0, 16), (8, 32), (24, 64),
+                   (56, 128), (120, 256)]
+# rows a descent touches, of C * depth: 312 of 2048 at depth 8 (RNN-B,
+# CNN-L), 104 of 384 at depth 6 (MLP-B)
+DESCENT_ROWS = {1: 2, 2: 8, 3: 24, 4: 40, 5: 64, 6: 104, 7: 176, 8: 312}
+
+
+@pytest.mark.parametrize("depth", range(1, 9))
+def test_descent_windows(depth):
+    """Each level's window is whole 8-row tiles clamped to C and covers the
+    level's nodes 2^l - 1 ... 2^(l+1) - 2."""
+    c = 2 ** depth
+    windows = descent_windows(depth)
+    assert windows == (tuple(DESCENT_WINDOWS[:depth]) if depth >= 3
+                       else ((0, c),) * depth)
+    for level, (lo, hi) in enumerate(windows):
+        assert lo % 8 == 0 and (hi % 8 == 0 or hi == c) and hi <= c
+        assert lo <= 2 ** level - 1 and 2 ** (level + 1) - 2 < hi
+    assert sum(hi - lo for lo, hi in windows) == DESCENT_ROWS[depth]
+
+
+def _bst_problem(rng, depth, k):
+    """Groups of one feature whose depth-``depth`` trees split ``[0, C)``
+    in order at the half-integers, so flows at 0, 1, ..., C and at every
+    threshold reach every node from both sides and every leaf; each group
+    sees the values in its own order. Returns ``x [2C, k, 1]``, the split
+    features and the thresholds."""
+    c = 2 ** depth
+    nodes = np.arange(c - 1)
+    level = np.array([int(n + 1).bit_length() - 1 for n in nodes])
+    span = c >> level                                  # leaves under a node
+    thr = (nodes - (2 ** level - 1)) * span + span // 2 - 0.5
+    values = np.concatenate([np.arange(c + 1), thr]).astype(np.float32)
+    x = np.stack([rng.permutation(values) for _ in range(k)], axis=1)
+    return (jnp.asarray(x[:, :, None]), jnp.zeros((k, c - 1), jnp.int32),
+            jnp.asarray(np.tile(thr, (k, 1)), jnp.float32))
+
+
+@pytest.mark.parametrize("kernel", ["single", "stack"])
+@pytest.mark.parametrize("depth", range(1, 9))
+def test_mxu_descent_matches_oracle_at_every_depth(depth, kernel):
+    """The ``mxu`` body, windowed descent, ≡ the oracle at depths 1-8 on
+    flows placed on every threshold and between them, so a window that
+    misses a node flips a bit and picks another LUT row. ``stack``: two
+    fused banks against the chained single-bank oracle; the first bank's
+    table holds multiples of 1/2, so the second bank's inputs land exactly
+    on its thresholds too."""
+    rng = np.random.default_rng(depth)
+    k, n_out, c = 4, 3, 2 ** depth
+    x, feats, thr = _bst_problem(rng, depth, k)
+    t = x.shape[0]
+    lut = jnp.asarray(rng.normal(size=(k, c, n_out)), jnp.float32)
+    bias = jnp.asarray(rng.normal(size=n_out), jnp.float32)
+    if kernel == "single":
+        got = fuzzy_lut_pallas(
+            x.reshape(t, k), *bank_layout(prepare_feat_onehot(feats, 1), thr,
+                                          lut, bias),
+            depth=depth, n_out=n_out, interpret=True, strategy="mxu")
+        want = fuzzy_lut_matmul_ref(x, feats, thr, lut, bias)
+    else:
+        # bank 1: k groups -> k values in [0, C], each a multiple of 1/2
+        lut1 = jnp.asarray(rng.integers(0, 2 * c // k + 1, size=(k, c, k)) / 2,
+                           jnp.float32)
+        h = fuzzy_lut_matmul_ref(x, feats, thr, lut1)
+        assert float(jnp.mean(jnp.any(h[:, :, None] == thr[None], -1))) > 0
+        want = fuzzy_lut_matmul_ref(h[:, :, None], feats, thr, lut, bias)
+        lut2 = jnp.pad(lut, ((0, 0), (0, 0), (0, k - n_out)))
+        bias2 = jnp.pad(bias, (0, k - n_out))
+        got = fuzzy_lut_stack_pallas(
+            x.reshape(t, k), *stack_layout(
+                jnp.stack([prepare_feat_onehot(feats, 1)] * 2),
+                jnp.stack([thr, thr]), jnp.stack([lut1, lut2]),
+                jnp.stack([jnp.zeros(k), bias2])),
+            depth=depth, ks=(k, k), n_out=n_out, interpret=True,
+            strategy="mxu")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_resolve_strategy_refuses_compiled_lookup():
